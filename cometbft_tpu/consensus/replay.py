@@ -79,7 +79,7 @@ class Handshaker:
             # Pinpoint divergence at the FIRST height whose replayed app
             # hash disagrees with the stored per-height ABCI response,
             # not just at the tip — an app-hash mismatch was observed
-            # once as a contention-timed flake (docs/r04-report.md), and
+            # once as a contention-timed flake, and
             # "which height first diverged" is the fact a post-mortem
             # needs to separate original-run misbehavior from replay
             # misbehavior.

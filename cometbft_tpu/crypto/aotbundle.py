@@ -43,8 +43,6 @@ from . import plan as _plan
 
 _MAGIC = "cmt-aot"
 _FORMAT = 1
-_REPO = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
 
 _LOADED: dict[str, object] = {}      # bucket key -> loaded executable
 _INFO: dict = {"status": "absent", "buckets": {}}
@@ -101,8 +99,10 @@ def default_path(dir_: str | None = None, plan=None) -> str:
     per fingerprint, so a jax upgrade builds beside the old bundle
     instead of clobbering it; a mesh tag keeps sharded bundles beside
     the single-device one — the plan hash deliberately excludes the
-    mesh shape).  Default dir sits next to the persistent XLA cache."""
-    base = dir_ or os.path.join(_REPO, ".jax_cache", "aot")
+    mesh shape).  Default dir sits under the persistent XLA cache's."""
+    from ..jaxenv import compile_cache_dir
+
+    base = dir_ or os.path.join(compile_cache_dir(), "aot")
     plan = plan or _plan.active()
     nd = _plan.mesh_size(plan)
     tag = f"-m{nd}" if nd > 1 else ""
@@ -355,6 +355,7 @@ def load(path: str | None = None, plan=None) -> dict:
         return _set_info({"status": "stale", "version": want,
                           "path": path, "plan": _plan.describe(plan),
                           "buckets": {}})
+    import jax
     from jax.experimental import serialize_executable as se
 
     from . import batch as _b
@@ -362,6 +363,7 @@ def load(path: str | None = None, plan=None) -> dict:
     _b._jit_env()
     statuses: dict[str, str] = {}
     nd = _plan.mesh_size(plan)
+    devs = jax.devices()
     for bucket in _plan.enumerate_buckets(plan):
         k = bucket.key
         if nd > 1 and bucket.kind not in ("tables", "bls_agg"):
@@ -370,8 +372,13 @@ def load(path: str | None = None, plan=None) -> dict:
     for key, ent in (doc.get("buckets") or {}).items():
         try:
             in_tree, out_tree = pickle.loads(ent["trees"])
+            # load onto the devices the bucket was compiled for: the
+            # plan's mesh for an @m<D> bucket, the default device
+            # otherwise (left alone, jax loads it onto EVERY local
+            # device and the first call fails on the shard count)
             _LOADED[key] = se.deserialize_and_load(
-                ent["payload"], in_tree, out_tree)
+                ent["payload"], in_tree, out_tree,
+                execution_devices=devs[:nd if "@m" in key else 1])
             statuses[key] = "warm"
         except Exception as e:
             # per-bucket degrade with a REASON in /status (the r13 CPU

@@ -245,27 +245,22 @@ def _kernel_wanted(n: int) -> bool:
 
 
 def _kernel_jits():
-    """(jit(merkle_inner_level), jit(sha256_blocks)) after the shared
-    hardening (CPU-pin defense + persistent compile cache), or None when
-    jax is unusable.  Import stays lazy: merkle is on many non-JAX
-    paths."""
+    """(jit(merkle_inner_level), jit(sha256_blocks)) with the persistent
+    compile cache on, or None when jax cannot be imported.  Import stays
+    lazy: merkle is on many non-JAX paths."""
     global _KERNEL_JITS
     if _KERNEL_JITS is None:
         try:
             import jax
-
-            from ..jaxenv import enable_compile_cache, harden_cpu_pinned_env
+        except ImportError:
+            _KERNEL_JITS = ()
+        else:
+            from ..jaxenv import enable_compile_cache
             from ..ops import sha256 as _s
 
-            harden_cpu_pinned_env()
-            try:
-                enable_compile_cache()
-            except Exception:
-                pass             # cache dir unwritable: compile-only
+            enable_compile_cache()
             _KERNEL_JITS = (jax.jit(_s.merkle_inner_level),
                             jax.jit(_s.sha256_blocks), _s)
-        except Exception:
-            _KERNEL_JITS = ()
     return _KERNEL_JITS if _KERNEL_JITS else None
 
 

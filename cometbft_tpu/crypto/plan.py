@@ -40,7 +40,7 @@ from dataclasses import dataclass, field, replace
 
 # Default bucket tables (moved verbatim from crypto/batch.py r12).
 # Lane buckets cap at 4096: measured on TPU v5e, verify throughput peaks
-# at 2048-4096 lanes and HALVES by 10240 (docs/bench/r04-notes.md);
+# at 2048-4096 lanes and HALVES by 10240 (round 4, older kernels);
 # oversized batches chunk at the cap.  Valset TABLE rows bucket
 # separately and keep growing past the cap: a cached per-valset table
 # must hold every validator (the gather indexes into it, it cannot

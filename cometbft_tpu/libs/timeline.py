@@ -40,7 +40,7 @@ from __future__ import annotations
 
 import math
 
-# waterfall phase taxonomy, in commit order.  Each phase starts at its
+# waterfall phase classification, in commit order.  Each phase starts at its
 # mark and runs to the next present mark (the last runs to the commit):
 #   propose    height start -> proposal received (includes commit-wait)
 #   gossip     proposal received -> block parts complete

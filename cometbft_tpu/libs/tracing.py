@@ -26,7 +26,7 @@ Design constraints, in order:
 - **Bounded memory.**  The ring is a ``deque(maxlen=N)``; old records
   fall off the back.  N is ``[instrumentation] tracing_ring_size``.
 
-Span taxonomy (see ``docs/explanation/observability.md``): records carry
+Span classification (see ``docs/explanation/observability.md``): records carry
 a ``sub`` (subsystem: ``consensus``, ``abci``, ``crypto.sched``,
 ``crypto.kernel``, ``wal``, ``mempool``), a ``name`` (one word: ``step``,
 ``call``, ``dispatch``, ``fsync``...), and free-form ``attrs``.  Spans

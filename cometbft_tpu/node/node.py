@@ -111,6 +111,7 @@ class Node:
         self.name = "node"
         self.doctor_report = None
         self.compile_bundle_info = None
+        self.verify_device_info = None
         self.light_serve = None
         self._started = False
         self._data_lock = None
@@ -466,6 +467,14 @@ class Node:
                 ring_size=self.config.instrumentation.tracing_ring_size)
         # (the fault-injection plane was armed in create(), before the
         # stores opened — open-time sites must see the schedule)
+        from ..crypto import batch as cryptobatch
+
+        # resolve the configured backend against the devices of THIS
+        # process before anything listens: "tpu" without a TPU refuses
+        # to start, "auto" without a chip says (once, and in /status)
+        # that it verifies on the host
+        self.verify_device_info = cryptobatch.device_info(
+            self.config.base.signature_backend)
         host, port = _parse_laddr(self.config.p2p.laddr) \
             if self.config.p2p.laddr else ("127.0.0.1", 0)
         self.listen_addr = await self.transport.listen(host, port)
@@ -507,7 +516,6 @@ class Node:
                 "rpc.overload_shed_lag_s is set but the loop watchdog is "
                 "disabled (instrumentation.loop_stall_threshold_s = 0): "
                 "overload shedding is inactive")
-        from ..crypto import batch as cryptobatch
         from ..crypto import plan as deviceplan
 
         # the declarative device plan drives the batched verifier AND
@@ -547,55 +555,44 @@ class Node:
             secp._native_lib()
 
         asyncio.get_running_loop().run_in_executor(None, _warm_native)
-        if self.config.base.device_warmup and \
-                self.config.base.signature_backend in ("tpu", "jax",
-                                                       "auto"):
+        backend = self.config.base.signature_backend
+        if self.verify_device_info["route"] != "device":
+            # host-verifying node: nothing to pre-compile
+            if backend == "auto":
+                self.compile_bundle_info = {"status": "skipped_no_device"}
+        elif self.config.base.device_warmup:
             # pre-compile hot bucket shapes off the event loop so the
-            # first commit verification doesn't stall consensus; under
-            # "auto" the device probe itself runs in the executor too
-            # (it may block on accelerator discovery)
-            backend = self.config.base.signature_backend
+            # first commit verification doesn't stall consensus
             bundle_on = self.config.base.compile_bundle_enable
             bundle_dir = self.config.base.compile_bundle_dir or None
 
             def _warm():
-                if backend == "auto" and \
-                        cryptobatch._accelerator_device() is None:
-                    self.compile_bundle_info = {
-                        "status": "skipped_no_device"}
-                    return          # CPU-only: nothing to pre-compile
                 from ..crypto import aotbundle
 
                 # default hot shapes, plus the buckets the CURRENT
                 # valset actually dispatches — a large network's first
-                # commit must not pay a cold XLA compile (VERDICT r3
-                # weak 1a).  The same shapes become the plan's warm set
-                # so the bundle covers the cached-gather route (the
-                # real commit hot path), keyed to this valset's TABLE
-                # bucket.
+                # commit must not pay a cold XLA compile.  The same
+                # shapes become the plan's warm set so the bundle
+                # covers the cached-gather route (the real commit hot
+                # path), keyed to this valset's TABLE bucket.
                 lanes = {256, 1024}
                 vsizes = ()
-                try:
-                    st = self.state_store.load()
-                    if st is not None:
-                        n_vals = len(st.validators.validators)
-                        if n_vals:
-                            lanes.update(
-                                cryptobatch.buckets_for_batch(n_vals))
-                            # the dense Light path dispatches the
-                            # ~2/3-power scope, not the full set
-                            lanes.update(cryptobatch.buckets_for_batch(
-                                (2 * n_vals) // 3 + 1))
-                            if n_vals > max(lanes):
-                                vsizes = (n_vals,)
-                            table = deviceplan.bucket(
-                                n_vals,
-                                deviceplan.active().table_buckets)
-                            deviceplan.configure(
-                                warm_lanes=tuple(sorted(lanes)),
-                                warm_tables=(table,))
-                except Exception:
-                    pass
+                st = self.state_store.load()
+                n_vals = len(st.validators.validators) \
+                    if st is not None else 0
+                if n_vals:
+                    lanes.update(cryptobatch.buckets_for_batch(n_vals))
+                    # the dense Light path dispatches the ~2/3-power
+                    # scope, not the full set
+                    lanes.update(cryptobatch.buckets_for_batch(
+                        (2 * n_vals) // 3 + 1))
+                    if n_vals > max(lanes):
+                        vsizes = (n_vals,)
+                    table = deviceplan.bucket(
+                        n_vals, deviceplan.active().table_buckets)
+                    deviceplan.configure(
+                        warm_lanes=tuple(sorted(lanes)),
+                        warm_tables=(table,))
                 if bundle_on:
                     # warm boot: load the versioned AOT bundle FIRST so
                     # the warmup below (and the first real commit) finds
@@ -625,7 +622,15 @@ class Node:
                         self.compile_bundle_info = {"status": "error",
                                                     "error": repr(e)}
 
-            asyncio.get_running_loop().run_in_executor(None, _warm)
+            warm = asyncio.get_running_loop().run_in_executor(None, _warm)
+            if backend == "tpu":
+                # "tpu" means the chip verifies: a hot shape its
+                # compiler refuses is a start-up failure, and waiting
+                # the compiles out here keeps the first commits from
+                # being abandoned to the host mid-compile
+                await warm
+            else:
+                warm.add_done_callback(self._warmup_done)
         if self.syncer is not None:
             self.statesync_done = asyncio.create_task(
                 self._run_statesync())
@@ -647,6 +652,17 @@ class Node:
                     wal_tail_records=inst.watchdog_wal_tail)
                 await self.liveness_watchdog.start()
         self._started = True
+
+    def _warmup_done(self, fut) -> None:
+        """Background warm-up ("jax"/"auto" with a device) ended: a
+        shape that failed to compile is logged, not dropped with the
+        future — the shape compiles on demand or degrades that dispatch
+        to the host on the device-health gauge."""
+        if not fut.cancelled() and fut.exception() is not None:
+            from ..libs import log as _tmlog
+
+            _tmlog.logger("node", node=self.name).error(
+                "device warm-up failed", err=repr(fut.exception()))
 
     async def stop(self) -> None:
         if self.statesync_done is not None:

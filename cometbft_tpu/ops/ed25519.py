@@ -23,8 +23,8 @@ arithmetic inside runs **limb-major** ``(20, B)`` (``ops/fe_lm.py``):
 the batch rides the TPU's 128-wide vector lane dimension instead of the
 20-limb axis (~16% utilization the other way), and the field multiply is
 a fusable shifted accumulation with no ``(B, 20, 39)`` Toeplitz
-intermediate (the measured large-batch HBM cliff of round 4 —
-docs/bench/r04-notes.md).  Measured on the full pipeline (CPU
+intermediate (the measured large-batch HBM cliff of round 4).
+Measured on the full pipeline (CPU
 rehearsal): 1.26-1.63x over batch-major, growing with batch size.  The
 transposes at the boundary are free under jit relative to the ladder.
 The SHA-512 and mod-L scalar pipelines stay batch-major — their outputs

@@ -132,8 +132,7 @@ def _mul_shift(a, b):
     the 39 columns, no (…,20,39) intermediate.  Candidate fix for the
     measured large-batch HBM cliff (TPU v5e: einsum throughput halves
     past ~4k lanes because the 32MB-per-mul Toeplitz intermediate falls
-    out of VMEM — docs/bench/r04-notes.md); fully fusable elementwise
-    graph instead."""
+    out of VMEM, round 4); fully fusable elementwise graph instead."""
     out = jnp.zeros(a.shape[:-1] + (NCOLS,), jnp.int32)
     for i in range(NLIMBS):
         out = out.at[..., i:i + NLIMBS].add(a[..., i:i + 1] * b)
@@ -141,8 +140,8 @@ def _mul_shift(a, b):
 
 
 # Selected at import: the einsum form is the measured default; the shift
-# form is promotable once hardware numbers exist for it (the chip was
-# wedged when it landed — see scripts/kern_layout_probe.py).
+# form is promotable once hardware numbers exist for it (see
+# scripts/kern_layout_probe.py).
 _MUL_IMPL = {"einsum": _mul_einsum, "shift": _mul_shift}
 
 

@@ -3,8 +3,8 @@
 The batch-major layout (``ops/fe.py``, elements ``(B, 20)``) puts the
 20-limb axis on the TPU's 128-wide vector lane dimension — ~16% lane
 utilization — and its einsum multiply materializes a ``(B, 20, 39)``
-Toeplitz intermediate that falls out of VMEM past ~4k lanes (measured:
-docs/bench/r04-notes.md).  This module flips the layout: the BATCH rides
+Toeplitz intermediate that falls out of VMEM past ~4k lanes (measured
+on a v5e in round 4).  This module flips the layout: the BATCH rides
 the vector lanes, limbs ride the sublane axis, and the multiply is 20
 statically-shifted row-accumulations with no Toeplitz intermediate.
 Measured on the full verify pipeline (CPU rehearsal,

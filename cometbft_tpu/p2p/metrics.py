@@ -82,7 +82,7 @@ def p2p_metrics() -> SimpleNamespace:
         misbehavior=m.counter(
             "p2p_peer_misbehavior_total",
             "misbehavior events reported to the peer scorer, by typed "
-            "event (see p2p/quality.py taxonomy)"),
+            "event (see p2p/quality.py classification)"),
         peer_bans=m.counter(
             "p2p_peer_bans_total",
             "timed bans issued by the peer scorer, by the event that "

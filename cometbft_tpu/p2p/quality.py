@@ -34,7 +34,7 @@ from __future__ import annotations
 
 from ..libs import clock
 
-# Event taxonomy: every layer that detects misbehavior reports one of
+# Event classification: every layer that detects misbehavior reports one of
 # these (severity-weighted; see docs/explanation/peer-quality.md for
 # the rationale per event).  The default thresholds are 5 (disconnect)
 # and 10 (ban): e.g. two bad blocks ban, five invalid votes disconnect.

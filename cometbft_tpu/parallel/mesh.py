@@ -37,21 +37,6 @@ def batch_mesh(devices=None) -> Mesh:
     return Mesh(devs, axis_names=(deviceplan.active().mesh_axis,))
 
 
-def _distributed_initialized() -> bool:
-    """Version-safe probe of the jax distributed runtime, public API
-    only: ``jax.distributed.is_initialized`` where it exists (jax >=
-    0.4.34), else treat the runtime as uninitialized and rely on the
-    re-init guard below.  Never reaches into private jax modules — that
-    layout has no stability contract and broke this probe once."""
-    probe = getattr(jax.distributed, "is_initialized", None)
-    if probe is None:
-        return False
-    try:
-        return bool(probe())
-    except Exception:
-        return False
-
-
 def init_multihost(coordinator: str | None = None,
                    num_processes: int | None = None,
                    process_id: int | None = None) -> Mesh:
@@ -78,19 +63,13 @@ def init_multihost(coordinator: str | None = None,
             num_processes = int(os.environ["JAX_NUM_PROCESSES"])
         if process_id is None and "JAX_PROCESS_ID" in os.environ:
             process_id = int(os.environ["JAX_PROCESS_ID"])
-        if not _distributed_initialized():
+        if not jax.distributed.is_initialized():
             # None process args let jax auto-detect cluster membership
-            # (TPU pods).  Where the public probe is absent the runtime
-            # may already be live, so a re-init raising "already
-            # initialized" is absorbed rather than fatal.
-            try:
-                jax.distributed.initialize(
-                    coordinator_address=coordinator,
-                    num_processes=num_processes,
-                    process_id=process_id)
-            except RuntimeError as e:
-                if "already" not in str(e).lower():
-                    raise
+            # (TPU pods)
+            jax.distributed.initialize(
+                coordinator_address=coordinator,
+                num_processes=num_processes,
+                process_id=process_id)
     return batch_mesh()
 
 

@@ -117,6 +117,9 @@ async def status(env: Environment) -> dict:
         # AOT compile-bundle state (crypto/aotbundle): version, plan
         # shape and per-bucket cold/warm — whether this node booted warm
         "compile_bundle": getattr(node, "compile_bundle_info", None),
+        # what signature_backend resolved to in this process: the JAX
+        # platform found and whether batches route to device or host
+        "verify_device": getattr(node, "verify_device_info", None),
         # light-serving tier tallies (light/serve.py): cache hit/miss/
         # eviction counts, proofs and blocks served, anchor verdicts
         "light_serve": (node.light_serve.stats()

@@ -1,4 +1,4 @@
-"""Seeded byzantine adversaries speaking the PR 9 misbehavior taxonomy.
+"""Seeded byzantine adversaries speaking the PR 9 misbehavior classification.
 
 Each adversary is attached to a :class:`~cometbft_tpu.sim.node.SimNode`
 that otherwise runs the honest stack — the attack is a wrapper around
@@ -18,7 +18,7 @@ Kinds (``KINDS``):
 - ``amnesiac`` — the forgetful voter: a seeded fraction of its own vote
   broadcasts are silently withheld (it voted, gossip never hears).
   Nothing provable ever hits the wire — pure liveness pressure, the
-  taxonomy's not-slashable quadrant.
+  classification's not-slashable quadrant.
 - ``spammer`` — invalid-part/proposal spammer: periodically broadcasts
   block parts with garbage payloads and fake merkle proofs targeted at
   the net's current height/round (plus the occasional non-msgpack

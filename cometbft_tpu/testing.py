@@ -44,6 +44,52 @@ def dense_signature_batch(bsz: int, msg_len: int = 120, seed: int = 7,
     return (pubs, rs, ss, blocks, active), host_items
 
 
+def zip215_edge_cases(seed: int = 7, msg_len: int = 120) -> list[tuple]:
+    """ZIP-215 edge cases as ``(label, pub, msg, sig)`` tuples on
+    special PUBKEYS (so a valset can carry them as rows):
+    a mixed-order key (A' + an order-8 torsion point, signature crafted
+    against the mixed encoding), the non-canonical identity (y = 1 + p)
+    with [S]B == R, the same key with a small-order R, and with a
+    non-canonical (y >= p) R.  Expected verdicts are the oracle's
+    (``crypto/_ed25519_py.verify_zip215``) — all four are accepts."""
+    import hashlib
+
+    from .crypto import _ed25519_py as ref
+
+    rng = np.random.default_rng(seed)
+    while True:                 # a point of exact order 8
+        pt = ref.pt_decompress_zip215(rng.bytes(32))
+        if pt is None:
+            continue
+        t8 = ref.pt_mul(ref.L, pt)
+        if not ref.pt_equal(ref.pt_mul(4, t8), ref.IDENTITY):
+            break
+    h0 = hashlib.sha512(rng.bytes(32)).digest()
+    a_sc, prefix = ref._clamp(h0[:32]), h0[32:]
+    mixed = ref.pt_compress(ref.pt_add(ref.pt_mul(a_sc, ref.BASE), t8))
+    m = rng.bytes(msg_len)
+    r_sc = ref.sc_reduce64(hashlib.sha512(prefix + m).digest())
+    r_enc = ref.pt_compress(ref.pt_mul(r_sc, ref.BASE))
+    k_sc = ref.sc_reduce64(hashlib.sha512(r_enc + mixed + m).digest())
+    cases = [("mixed_order_key", mixed, m,
+              r_enc + ((r_sc + k_sc * a_sc) % ref.L).to_bytes(32, "little"))]
+    ident_nc = (1 + ref.P).to_bytes(32, "little")
+    r_sc = int.from_bytes(rng.bytes(32), "little") % ref.L
+    zero = (0).to_bytes(32, "little")
+    cases += [
+        ("noncanonical_identity_key", ident_nc, rng.bytes(msg_len),
+         ref.pt_compress(ref.pt_mul(r_sc, ref.BASE))
+         + r_sc.to_bytes(32, "little")),
+        ("small_order_r", ident_nc, rng.bytes(msg_len),
+         ref.pt_compress(t8) + zero),
+        ("noncanonical_r", ident_nc, rng.bytes(msg_len), ident_nc + zero),
+    ]
+    for label, pub, msg, sig in cases:
+        if not ref.verify_zip215(pub, msg, sig):
+            raise ValueError(f"oracle refuses edge case {label}")
+    return cases
+
+
 def bls_priv_from_secret(secret: bytes):
     """Deterministic bls12_381 key for tests/benches (the BLS analog of
     ``Ed25519PrivKey.from_secret``): RFC 9380 KeyGen over the padded

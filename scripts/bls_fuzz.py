@@ -4,8 +4,6 @@ Python-oracle accept (sampled)."""
 import os, sys, random, time
 os.environ["JAX_PLATFORMS"] = "cpu"
 sys.path.insert(0, __import__("os").path.join(__import__("os").path.dirname(__file__), ".."))
-from cometbft_tpu.jaxenv import harden_cpu_pinned_env
-harden_cpu_pinned_env()
 from cometbft_tpu.crypto import _bls12381_py as B
 from cometbft_tpu.crypto import bls12381 as keys
 

@@ -6,7 +6,7 @@ projection the way an operator would —
 - ``GET /consensus_timeline?n=K`` must answer 200 with per-height
   waterfalls for every committed height,
 - each complete waterfall's phases must be a prefix-ordered subset of
-  the canonical taxonomy (propose -> gossip -> prevote -> precommit ->
+  the canonical classification (propose -> gossip -> prevote -> precommit ->
   commit) with contiguous, non-negative segments,
 - the residual buckets (gossip_wait/verify/app/wal/idle) must sum to
   the measured commit latency — never more,
@@ -45,7 +45,7 @@ def fetch(url: str) -> tuple[int, bytes]:
 def check_waterfall(wf: dict, phase_order: list) -> str | None:
     """Return a failure reason, or None if the waterfall is sound."""
     phases = [p["phase"] for p in wf["phases"]]
-    # present phases must appear in taxonomy order (absent marks — a
+    # present phases must appear in classification order (absent marks — a
     # catch-up commit, an evicted record — drop phases, never reorder)
     idx = [phase_order.index(p) for p in phases if p in phase_order]
     if len(idx) != len(phases) or idx != sorted(idx):
@@ -119,7 +119,7 @@ async def main() -> int:
             return 1
         order = result.get("phases") or []
         if order[:2] != ["propose", "gossip"]:
-            print(f"FAIL: bad phase taxonomy {order}", file=sys.stderr)
+            print(f"FAIL: bad phase classification {order}", file=sys.stderr)
             return 1
         wfs = result.get("waterfalls") or []
         done = [w for w in wfs if w["complete"]]

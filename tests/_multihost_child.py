@@ -18,9 +18,8 @@ if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (
         flags + " --xla_force_host_platform_device_count=2").strip()
 
-from cometbft_tpu.jaxenv import enable_compile_cache, harden_cpu_pinned_env
+from cometbft_tpu.jaxenv import enable_compile_cache
 
-harden_cpu_pinned_env()
 enable_compile_cache()
 
 import numpy as np

@@ -1,5 +1,5 @@
 """Differential fuzzer: native/secp256k1.cpp vs the OpenSSL-backed
-Python path (VERDICT r3 item 1b).
+Python path.
 
 Every triple is derived from a seeded PRNG and RFC 6979 signing, so ANY
 mismatch is replayable from the printed (seed, index) alone — the exact

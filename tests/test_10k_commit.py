@@ -1,4 +1,4 @@
-"""10k-validator consensus-path test (VERDICT r4 next 9): one REAL
+"""10k-validator consensus-path test: one REAL
 commit over a synthetic 10,000-validator set driven through the
 production VerifyCommit dense path on the device route — the
 cached-table gather + RLC dispatch (`crypto/batch.py`

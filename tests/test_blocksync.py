@@ -74,7 +74,7 @@ def test_batched_multi_commit_flags_wrong_block_id():
 def test_fast_sync_over_tcp():
     """A late full node block-syncs a committed chain from 3 validators
     over real TCP, then follows via consensus (reactor.go:421-431
-    SwitchToConsensus; VERDICT round-1 item 4's bar)."""
+    SwitchToConsensus)."""
     from cometbft_tpu.abci.kvstore import KVStoreApplication
     from cometbft_tpu.config import Config, test_consensus_config
     from cometbft_tpu.node import Node

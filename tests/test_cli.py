@@ -1,6 +1,6 @@
 """CLI + multi-process e2e: `testnet` generates wired homes, `start` runs
 real node processes, RPC drives them — the reference's e2e tier
-(``test/e2e/README.md``) on one machine, and VERDICT item 9's bar:
+(``test/e2e/README.md``) on one machine:
 "the tier-2 testnet driven through the CLI + RPC instead of test harness
 internals"."""
 

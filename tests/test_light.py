@@ -178,7 +178,7 @@ def test_client_detects_forked_witness():
 
 
 def test_detector_trace_walk_two_sided_evidence():
-    """VERDICT r4 next 5: a fork at height H with divergence point H-k
+    """A fork at height H with divergence point H-k
     must yield evidence whose common_height is the TRUE fork height
     (trace examination, detector.go:285), two-sided evidence, and
     delivery to both honest parties — the witness receives the case
@@ -224,7 +224,7 @@ def test_detector_trace_walk_two_sided_evidence():
 
 
 def test_detector_drops_persistently_lagging_witness():
-    """VERDICT r4 weak 7: a witness that can never serve the height is
+    """A witness that can never serve the height is
     struck out after MAX_WITNESS_LAG_STRIKES consecutive misses instead
     of being retried forever; an agreeing witness survives."""
     from cometbft_tpu.light.detector import (MAX_WITNESS_LAG_STRIKES,

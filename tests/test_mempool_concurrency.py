@@ -1,4 +1,4 @@
-"""Mempool admission concurrency (VERDICT r3 item 9): check_tx no longer
+"""Mempool admission concurrency: check_tx no longer
 serializes on one lock across the app round-trip — one slow CheckTx must
 not stall other admissions — while the executor's update/flush critical
 section stays exclusive against in-flight admissions."""

@@ -234,53 +234,47 @@ def test_default_path_carries_mesh_tag():
 # --------------------------------------------- init_multihost public probe
 
 
-def test_distributed_probe_never_touches_private_api(monkeypatch):
+def test_init_multihost_asks_the_public_probe(monkeypatch):
     import types
 
     import jax
 
-    # a jax without the public probe (pre-0.4.34 layout): the probe must
-    # answer False from PUBLIC api alone, never import jax._src state
     calls = []
 
     def fake_init(**kw):
         calls.append(kw)
 
+    # runtime not live: initialize once, with exactly what was given
     monkeypatch.setattr(
         jax, "distributed",
-        types.SimpleNamespace(initialize=fake_init), raising=False)
-    assert M._distributed_initialized() is False
+        types.SimpleNamespace(initialize=fake_init,
+                              is_initialized=lambda: False), raising=False)
     M.init_multihost(coordinator="127.0.0.1:9999", num_processes=1,
                      process_id=0)
-    assert len(calls) == 1
+    assert calls == [{"coordinator_address": "127.0.0.1:9999",
+                      "num_processes": 1, "process_id": 0}]
 
-    # probe present and truthy: no re-init
+    # runtime live: no re-init
     monkeypatch.setattr(
         jax, "distributed",
         types.SimpleNamespace(initialize=fake_init,
                               is_initialized=lambda: True), raising=False)
-    assert M._distributed_initialized() is True
     M.init_multihost(coordinator="127.0.0.1:9999")
     assert len(calls) == 1                       # unchanged
 
-    # probe absent + runtime actually already live: the "already
-    # initialized" RuntimeError is absorbed, anything else propagates
-    def angry_init(**kw):
-        raise RuntimeError("jax.distributed.initialize was already called")
+    # an initialize that raises is the caller's to see, whatever it says
+    for msg in ("coordinator unreachable",
+                "jax.distributed.initialize was already called"):
+        def broken_init(msg=msg, **kw):
+            raise RuntimeError(msg)
 
-    monkeypatch.setattr(
-        jax, "distributed",
-        types.SimpleNamespace(initialize=angry_init), raising=False)
-    M.init_multihost(coordinator="127.0.0.1:9999")
-
-    def broken_init(**kw):
-        raise RuntimeError("coordinator unreachable")
-
-    monkeypatch.setattr(
-        jax, "distributed",
-        types.SimpleNamespace(initialize=broken_init), raising=False)
-    with pytest.raises(RuntimeError, match="unreachable"):
-        M.init_multihost(coordinator="127.0.0.1:9999")
+        monkeypatch.setattr(
+            jax, "distributed",
+            types.SimpleNamespace(initialize=broken_init,
+                                  is_initialized=lambda: False),
+            raising=False)
+        with pytest.raises(RuntimeError, match=msg.split()[-1]):
+            M.init_multihost(coordinator="127.0.0.1:9999")
 
 
 def test_mesh_module_has_no_private_jax_reach():

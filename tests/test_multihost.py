@@ -1,4 +1,4 @@
-"""Multi-host device mesh smoke test (VERDICT r4 next 6 / SURVEY §2.7
+"""Multi-host device mesh smoke test (SURVEY §2.7
 cross-host DCN path): two OS processes bootstrap one jax.distributed
 CPU cluster through ``parallel/mesh.py::init_multihost`` and run a
 lane-sharded verification step over the shared 4-device global mesh —

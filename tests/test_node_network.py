@@ -79,7 +79,7 @@ async def _stop_all(nodes):
 
 def test_four_nodes_commit_over_tcp():
     """4 single-process nodes on localhost TCP commit 10+ blocks with txs
-    gossiped via the mempool channel (VERDICT round-1 item 3's bar)."""
+    gossiped via the mempool channel."""
 
     async def main():
         nodes = await _make_net(4)

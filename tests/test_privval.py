@@ -125,8 +125,7 @@ def test_filepv_refuses_hrs_regression(tmp_path):
 
 
 def test_filepv_survives_restart_no_double_sign(tmp_path):
-    """Crash after signing: the restarted signer refuses to equivocate
-    (VERDICT item 6's bar)."""
+    """Crash after signing: the restarted signer refuses to equivocate."""
     pv = _pv(tmp_path)
 
     async def main():

@@ -1,4 +1,4 @@
-"""Measured backend auto-routing (VERDICT r4 weak 3 / next 3): under
+"""Measured backend auto-routing: under
 backend="auto" the dispatcher must never keep verifying on a device the
 router has measured slower than the native host path — with periodic
 exploration so a recovered device gets re-measured."""
@@ -88,7 +88,6 @@ def test_auto_backend_routes_slow_device_to_host(monkeypatch):
     router seeded from measurements, backend=auto serves from the native
     host batch and never dispatches to the device."""
     monkeypatch.setattr(B, "_accelerator_device", lambda: _FakeDevice())
-    monkeypatch.setattr(B, "_PROBE_RESULT", [True])
     B._ROUTER.observe("device", 8, 10.0)   # measured: painfully slow
     B._ROUTER.observe("host", 8, 0.001)
 
@@ -121,7 +120,6 @@ def test_device_timeout_feeds_pessimistic_sample(monkeypatch):
     """A bounded-wait abandonment charges the router the full wait, so
     subsequent auto batches route to host until the device answers."""
     monkeypatch.setattr(B, "_accelerator_device", lambda: _FakeDevice())
-    monkeypatch.setattr(B, "_PROBE_RESULT", [True])
     monkeypatch.setattr(B, "_device_call", lambda fn: None)  # wedged
 
     bv = B.TpuBatchVerifier(routed=True)
@@ -133,3 +131,58 @@ def test_device_timeout_feeds_pessimistic_sample(monkeypatch):
     # the pessimistic sample must now lose to any healthy host number
     B._ROUTER.observe("host", 8, 0.001)
     assert not B._ROUTER.prefer_device(8)
+
+
+# ------------------------------------------- "tpu" means TPU; cache placement
+
+
+def test_tpu_backend_on_a_cpu_only_process_raises():
+    """backend="tpu" must never be XLA:CPU counted as route="device":
+    verifier creation, the dense path and the node-start check all name
+    the platform found ("jax" stays whatever backend JAX has)."""
+    for call in (lambda: B.create_batch_verifier("tpu"),
+                 lambda: B._backend_wants_device("tpu", None, lanes=8),
+                 lambda: B.device_info("tpu")):
+        with pytest.raises(B.DeviceUnavailable, match="'cpu'"):
+            call()
+    assert isinstance(B.create_batch_verifier("jax"), B.TpuBatchVerifier)
+    assert B.device_info("jax")["route"] == "device"
+    info = B.device_info("auto")
+    assert (info["platform"], info["route"]) == ("cpu", "host")
+    assert B.device_info("cpu")["route"] == "host"
+
+
+@pytest.mark.parametrize("placed", [True, False],
+                         ids=["env_set", "env_unset"])
+def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path, placed):
+    """JAX_COMPILATION_CACHE_DIR set: the program sets no directory in
+    code (jax reads the variable itself) and the AOT bundle goes under
+    it; unset: both stay at the fixed <checkout>/.jax_cache."""
+    import os
+
+    import jax
+
+    from cometbft_tpu import jaxenv
+    from cometbft_tpu.crypto import aotbundle
+
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    fixed = os.path.join(checkout, ".jax_cache")
+    seen = []
+    real_update = jax.config.update
+
+    def spy(name, value):
+        seen.append(name)
+        if name != "jax_compilation_cache_dir":
+            real_update(name, value)
+
+    monkeypatch.setattr(jax.config, "update", spy)
+    if placed:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    jaxenv.enable_compile_cache()
+    assert ("jax_compilation_cache_dir" in seen) == (not placed)
+    want = str(tmp_path) if placed else fixed
+    assert jaxenv.compile_cache_dir() == want
+    assert os.path.dirname(aotbundle.default_path()) == \
+        os.path.join(want, "aot")

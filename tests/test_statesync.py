@@ -272,7 +272,7 @@ def test_syncer_offer_reject_format_and_sender():
 
 
 def test_concurrent_chunk_fetch_scales_with_peers():
-    """VERDICT r3 item 6: per-peer in-flight caps make restore bandwidth
+    """per-peer in-flight caps make restore bandwidth
     scale with the number of serving peers — doubling peers roughly
     halves wall-clock — while no peer ever holds more than
     MAX_INFLIGHT_PER_PEER outstanding requests."""

@@ -78,7 +78,7 @@ def test_basic_waterfall_phases_ordered_and_buckets_sum_to_total():
     assert wf["node"] == "n0" and wf["height"] == 5
     assert wf["complete"] and not wf["catchup"]
     assert wf["total_s"] == 5.0
-    # all five phases present, in taxonomy order, contiguous
+    # all five phases present, in classification order, contiguous
     assert [p["phase"] for p in wf["phases"]] == list(timeline.PHASES)
     cursor = 0.0
     for p in wf["phases"]:

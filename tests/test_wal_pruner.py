@@ -59,7 +59,7 @@ def test_wal_prunes_old_segments(tmp_path):
 
 
 def test_wal_endheight_search_reads_only_tail_segments(tmp_path):
-    """VERDICT r4 next 7: ``records_after_height`` binary-searches the
+    """``records_after_height`` binary-searches the
     segment list (autofile group.go:34-54 SearchForEndHeight parity)
     instead of decoding every record of every segment — a long-lived
     validator restarting with a big WAL must read O(log n) segment
