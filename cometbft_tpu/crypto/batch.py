@@ -28,6 +28,7 @@ dispatch at warm latency.
 
 from __future__ import annotations
 
+import contextvars
 import functools
 import threading
 import time
@@ -35,6 +36,7 @@ from abc import ABC, abstractmethod
 
 import numpy as np
 
+from ..libs import tracing
 from . import plan as _plan
 from .keys import ED25519_KEY_TYPE, PubKey, verify_ed25519_zip215
 
@@ -304,8 +306,16 @@ def _valset_tables(pubs_full, devices: tuple):
     skip decompression and table building on device."""
     key = (id(pubs_full), devices)
     ent = _VALSET_TABLES.get(key)
-    if ent is not None and ent[0] is pubs_full:
+    hit = ent is not None and ent[0] is pubs_full
+    with tracing.span("crypto.seam", "tables", hit=hit,
+                      rows=pubs_full.shape[0]):
+        if not hit:
+            ent = _build_valset_tables(key, pubs_full, devices)
         return ent[1], ent[2], ent[3]
+
+
+def _build_valset_tables(key, pubs_full, devices: tuple) -> tuple:
+    """A table-cache miss: build on the device, insert, return the entry."""
     n = pubs_full.shape[0]
     nb = _bucket(n, _plan.active().table_buckets)
     if len(devices) > 1:
@@ -315,7 +325,7 @@ def _valset_tables(pubs_full, devices: tuple):
     padded[n:] = pubs_full[0] if n else 0
     if len(devices) == 1:
         # pinned single chip: build the table THERE, not on the default
-        padded = _timed_put(padded, devices[0])
+        padded = _put(padded, devices[0])
     fn = None
     if not devices:
         # unpinned default-device build: a bundled table kernel skips
@@ -346,8 +356,8 @@ def _valset_tables(pubs_full, devices: tuple):
                     break
                 victim = next(iter(_VALSET_TABLES))
             _VALSET_TABLES.pop(victim)
-        _VALSET_TABLES[key] = (pubs_full, tab, ok, nb)
-    return tab, ok, nb
+        ent = _VALSET_TABLES[key] = (pubs_full, tab, ok, nb)
+    return ent
 
 
 def device_verify_ed25519_cached(valset_pubs, scope, pubs_rows, rs, ss,
@@ -372,18 +382,15 @@ def device_verify_ed25519_cached(valset_pubs, scope, pubs_rows, rs, ss,
         c = end - start
         sl = slice(start, end)
         bb = _chunk_bucket(c, devices)
-        _, r32, s32, blocks, active = _padded_lane_args(
-            pubs_rows[sl], rs[sl], ss[sl], msgs[sl], msg_lens[sl], bb)
-        idx = np.zeros((bb,), np.int32)
-        idx[:c] = np.asarray(scope[sl], np.int32)
-        idx[c:] = idx[0]
-        nb_blocks = blocks.shape[1]
+        lane_args, z10 = _pack(pubs_rows[sl], rs[sl], ss[sl], msgs[sl],
+                               msg_lens[sl], bb, scope=scope[sl])
+        nb_blocks = lane_args[3].shape[1]
         _note_mesh(devices, c, bb)
-        if c >= _rlc_min_lanes():
+        if z10 is not None:
             # steady-state fast path: one RLC verdict over the cached
             # tables (lane-sharded over a multi-chip mesh); a reject
             # falls through to per-lane localization
-            rl_args = (idx, r32, s32, blocks, active, _rlc_args(bb, c))
+            rl_args = lane_args + (z10,)
             if len(devices) > 1:
                 rfn = _aot_fn_mesh(f"rlc_gather:{n_pad}", bb, nb_blocks,
                                    devices)
@@ -396,16 +403,12 @@ def device_verify_ed25519_cached(valset_pubs, scope, pubs_rows, rs, ss,
                 if rfn is None:
                     rfn = _compiled_rlc_gather()
                     if place is not None:
-                        rl_args = _timed_put(rl_args, place)
-            t0 = time.perf_counter()
-            verdict = bool(np.asarray(rfn(tab, ok, *rl_args)))
-            _note_dispatch(rkind, bb, time.perf_counter() - t0)
-            if verdict:
+                        rl_args = _put(rl_args, place)
+            if _run(rkind, rfn, (tab, ok, *rl_args), c, bb):
                 _metrics()[1].inc(c, route="device_rlc" if len(devices) <= 1
                                   else "device_rlc_sharded")
                 results[start:end] = True
                 continue
-        lane_args = (idx, r32, s32, blocks, active)
         if len(devices) > 1:
             fn = _aot_fn_mesh(f"gather:{n_pad}", bb, nb_blocks, devices)
             if fn is None:
@@ -415,11 +418,9 @@ def device_verify_ed25519_cached(valset_pubs, scope, pubs_rows, rs, ss,
             if fn is None:
                 fn = _compiled_verify_gather(devices)
                 if place is not None:
-                    lane_args = _timed_put(lane_args, place)
-        t0 = time.perf_counter()
-        out = np.asarray(fn(tab, ok, *lane_args))
-        _note_dispatch("gather_sharded" if len(devices) > 1 else "gather",
-                       bb, time.perf_counter() - t0)
+                    lane_args = _put(lane_args, place)
+        out = _run("gather_sharded" if len(devices) > 1 else "gather",
+                   fn, (tab, ok, *lane_args), c, bb)
         results[start:end] = out[:c]
     return results
 
@@ -558,6 +559,54 @@ def _padded_lane_args(pubs, rs, ss, msgs, msg_lens, bb):
     return pad(pubs), pad(rs), pad(ss), blocks, active
 
 
+def _pack(pubs, rs, ss, msgs, msg_lens, bb, scope=None):
+    """One chunk's kernel arguments, packed under a ``pack`` span on both
+    device routes: the padded lane matrices (led by the chunk's table
+    rows ``scope`` on the cached route, by the pubkeys otherwise) and,
+    from the RLC threshold up, the coefficient draw (else None)."""
+    b = pubs.shape[0]
+    with tracing.span("crypto.seam", "pack", lanes=b, bucket=bb) as sp:
+        lead, r32, s32, blocks, active = _padded_lane_args(
+            pubs, rs, ss, msgs, msg_lens, bb)
+        if scope is not None:
+            lead = np.zeros((bb,), np.int32)
+            lead[:b] = np.asarray(scope, np.int32)
+            lead[b:] = lead[0]
+        z10 = _rlc_args(bb, b) if b >= _rlc_min_lanes() else None
+        if sp is not None:
+            sp.attrs["blocks"] = blocks.shape[1]
+    return (lead, r32, s32, blocks, active), z10
+
+
+def _put(tree, place):
+    """``jax.device_put`` of a dispatch's arguments under a ``put`` span.
+    The copy is an asynchronous enqueue: the span is the host's time in
+    it, never a wait for the copy to land."""
+    import jax
+
+    with tracing.span("crypto.seam", "put") as sp:
+        if sp is not None:
+            sp.attrs["bytes"] = sum(
+                a.nbytes for a in jax.tree_util.tree_leaves(tree))
+        return jax.device_put(tree, place)
+
+
+def _run(kind: str, fn, args: tuple, lanes: int, bb: int) -> np.ndarray:
+    """One compiled program: the ``launch`` span ends when the call
+    returns its (unready) result, the ``readback`` span when the verdict
+    is on the host."""
+    t0 = time.perf_counter()
+    with tracing.span("crypto.seam", "launch", kind=kind, lanes=lanes,
+                      bucket=bb):
+        out = fn(*args)
+    with tracing.span("crypto.seam", "readback", kind=kind) as sp:
+        out = np.asarray(out)
+        if sp is not None:
+            sp.attrs["ok"] = bool(out.all())
+    _note_dispatch(kind, bb, time.perf_counter() - t0)
+    return out
+
+
 def _single_device_place(device, devices: tuple):
     """The chip a non-sharded dispatch must pin its arrays to: the
     caller's pin wins, else a configured 1-device set (set_devices must
@@ -593,7 +642,7 @@ def _device_verify_chunk(pubs, rs, ss, msgs, msg_lens, device):
     b = pubs.shape[0]
     devices = _resolve_devices(device)
     bb = _chunk_bucket(b, devices)
-    args = _padded_lane_args(pubs, rs, ss, msgs, msg_lens, bb)
+    args, z10 = _pack(pubs, rs, ss, msgs, msg_lens, bb)
     nb = args[3].shape[1]           # hash-block bucket of this dispatch
     _note_mesh(devices, b, bb)
     if len(devices) > 1:
@@ -601,49 +650,36 @@ def _device_verify_chunk(pubs, rs, ss, msgs, msg_lens, device):
         # mesh (no per-device fan-out) — RLC verdict first (device-local
         # partial sums, O(windows) cross-chip points), per-lane sharded
         # program to localize a rejection
-        if b >= _rlc_min_lanes():
-            rargs = args + (_rlc_args(bb, b),)
+        if z10 is not None:
             rfn = _aot_fn_mesh("rlc", bb, nb, devices)
             if rfn is None:
                 rfn = _compiled_rlc_sharded(devices)
-            t0 = time.perf_counter()
-            verdict = bool(np.asarray(rfn(*rargs)))
-            _note_dispatch("rlc_sharded", bb, time.perf_counter() - t0)
-            if verdict:
+            if _run("rlc_sharded", rfn, args + (z10,), b, bb):
                 _metrics()[1].inc(b, route="device_rlc_sharded")
                 return np.ones((b,), bool)
         fn = _aot_fn_mesh("verify", bb, nb, devices)
         if fn is None:
             fn = _compiled_verify_sharded(devices)
-        t0 = time.perf_counter()
-        out = np.asarray(fn(*args))
-        _note_dispatch("verify_sharded", bb, time.perf_counter() - t0)
-        return out[:b]
+        return _run("verify_sharded", fn, args, b, bb)[:b]
     place = _single_device_place(device, devices)
-    if b >= _rlc_min_lanes():
+    if z10 is not None:
         # one-shot RLC verdict first (the all-valid common case); a
         # reject falls through to the per-lane ladder for localization
-        rargs = args + (_rlc_args(bb, b),)
+        rargs = args + (z10,)
         rfn = _aot_fn("rlc", bb, nb, place)
         if rfn is None:
             rfn = _compiled_rlc()
             if place is not None:
-                rargs = _timed_put(rargs, place)
-        t0 = time.perf_counter()
-        verdict = bool(np.asarray(rfn(*rargs)))
-        _note_dispatch("rlc", bb, time.perf_counter() - t0)
-        if verdict:
+                rargs = _put(rargs, place)
+        if _run("rlc", rfn, rargs, b, bb):
             _metrics()[1].inc(b, route="device_rlc")
             return np.ones((b,), bool)
     fn = _aot_fn("verify", bb, nb, place)
     if fn is None:
         fn = _compiled_verify()
         if place is not None:
-            args = _timed_put(args, place)
-    t0 = time.perf_counter()
-    out = np.asarray(fn(*args))
-    _note_dispatch("verify", bb, time.perf_counter() - t0)
-    return out[:b]
+            args = _put(args, place)
+    return _run("verify", fn, args, b, bb)[:b]
 
 
 @functools.cache
@@ -702,8 +738,8 @@ def _kprof():
     multi-second/minute value is a cold XLA compile, a value near the
     dispatch p50 means the persistent compile cache served it.  Later
     dispatches of a seen shape land in
-    ``crypto_kernel_dispatch_seconds{kind}``; explicit host->device
-    placements land in ``crypto_device_transfer_seconds``."""
+    ``crypto_kernel_dispatch_seconds{kind}``.  Host->device placements
+    are the flight recorder's ``crypto.seam/put`` spans."""
     from ..libs import metrics as m
 
     return (
@@ -716,10 +752,6 @@ def _kprof():
                     "device kernel dispatch latency (warm shapes)",
                     buckets=(0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025,
                              0.05, 0.1, 0.25, 0.5, 1, 2.5)),
-        m.histogram("crypto_device_transfer_seconds",
-                    "host->device transfer latency (explicit device_put)",
-                    buckets=(0.0001, 0.00025, 0.0005, 0.001, 0.0025,
-                             0.005, 0.01, 0.05, 0.1)),
     )
 
 
@@ -730,40 +762,16 @@ def _note_dispatch(kind: str, lanes_bucket: int, seconds: float) -> None:
     """Record one compiled-kernel execution: the first (kind, bucket)
     sighting is the compile-or-cache gauge + a flight-recorder event,
     repeats are the warm dispatch histogram."""
-    gauge, first, hist, _ = _kprof()
+    gauge, first, hist = _kprof()
     key = (kind, lanes_bucket)
     if key not in _SEEN_SHAPES:
         _SEEN_SHAPES.add(key)
         gauge.set(seconds, kind=kind, lanes=str(lanes_bucket))
         first.inc(kind=kind)
-        from ..libs import tracing
-
         tracing.event("crypto.kernel", "first_dispatch", kind=kind,
                       lanes=lanes_bucket, dur_us=int(seconds * 1e6))
     else:
         hist.observe(seconds, kind=kind)
-
-
-def _timed_put(tree, place):
-    """``jax.device_put`` with transfer timing.  With the flight
-    recorder ON (deep-profiling opt-in) it blocks until the copy lands
-    so the histogram measures the real transfer; with tracing off (the
-    production default) it times only the enqueue — forcing a host sync
-    on every hot-path placement would forfeit the transfer/dispatch
-    overlap just to make a histogram prettier."""
-    import jax
-
-    from ..libs import tracing
-
-    t0 = time.perf_counter()
-    out = jax.device_put(tree, place)
-    if tracing.is_enabled():
-        try:
-            jax.block_until_ready(out)
-        except Exception:
-            pass
-    _kprof()[3].observe(time.perf_counter() - t0)
-    return out
 
 
 _DEVICE_WAIT_S = 2.0             # max time a verify waits on the device:
@@ -888,6 +896,17 @@ def _device_call(fn, patient: float = 0.0):
                         "chaos: injected device dispatch failure")
                 return inner()
     timeout = patient or _DEVICE_WAIT_S
+    queued = None
+    if tracing.is_enabled():
+        # from submit to the first instruction on the device-owner thread,
+        # whose spans then name the caller's open span as their parent
+        queued = tracing.begin("crypto.seam", "queue", patient=bool(patient),
+                               abandoned=False)
+        work, run_in = fn, contextvars.copy_context().run
+
+        def fn():
+            tracing.finish(queued)
+            return run_in(work)
     with _DEVICE_SUBMIT_LOCK:
         fut = _DEVICE_POOL.submit(fn)
         _DEVICE_INFLIGHT = fut
@@ -897,6 +916,8 @@ def _device_call(fn, patient: float = 0.0):
     except cf.TimeoutError:
         abandoned.inc()
         gauge.set(1)
+        if queued is not None:
+            queued.attrs["abandoned"] = True
         if not _DEGRADED_LOGGED:
             _DEGRADED_LOGGED = True
             from ..libs import log as _tmlog
@@ -913,6 +934,8 @@ def _device_call(fn, patient: float = 0.0):
         # consensus path
         abandoned.inc()
         gauge.set(1)
+        if queued is not None:
+            queued.attrs["abandoned"] = True
         if not _DEGRADED_LOGGED:
             _DEGRADED_LOGGED = True
             from ..libs import log as _tmlog
@@ -1154,15 +1177,27 @@ def verify_dense(backend: str, pubs, sigs, msgs, lens, device=None,
     ``patient`` queues behind an in-flight device dispatch instead of
     host-falling-back (the blocksync accumulator's staging mode; see
     :func:`_device_call`)."""
-    import numpy as np
+    k = pubs.shape[0]
+    if k == 0:
+        return True, np.zeros((0,), bool)
+    with tracing.span("crypto.seam", "verify_dense", lanes=k,
+                      patient=patient) as sp:
+        res, route = _verify_dense_routed(backend, pubs, sigs, msgs, lens,
+                                          device, valset_pubs, scope, patient)
+        if sp is not None:
+            sp.attrs["route"] = route
+        return res
+
+
+def _verify_dense_routed(backend, pubs, sigs, msgs, lens, device,
+                         valset_pubs, scope, patient) -> tuple:
+    """:func:`verify_dense`'s result and the route that gave it
+    (``device``, ``cpu_batch``, ``cpu``; None with no dense backend)."""
+    import time as _time
 
     from . import _native_ed25519 as _nat
 
     k = pubs.shape[0]
-    if k == 0:
-        return True, np.zeros((0,), bool)
-    import time as _time
-
     _, lanes, _ = _metrics()
     if _backend_wants_device(backend, device, lanes=k) \
             and k >= TpuBatchVerifier.MIN_DEVICE_LANES:
@@ -1180,7 +1215,7 @@ def verify_dense(backend: str, pubs, sigs, msgs, lens, device=None,
         if out is not None:
             _ROUTER.observe("device", k, _time.perf_counter() - t0)
             lanes.inc(k, route="device")
-            return bool(out.all()), out
+            return (bool(out.all()), out), "device"
         # device busy/wedged: bounded fallback to the native host batch.
         # Charge the router the full bounded wait so "auto" prefers the
         # host until the device measurably answers again.
@@ -1189,17 +1224,17 @@ def verify_dense(backend: str, pubs, sigs, msgs, lens, device=None,
     t0 = _time.perf_counter()
     res = _nat.batch_verify_dense(pubs, sigs, msgs, lens)
     if res is None:
-        return None
+        return None, None
     if res:
         _ROUTER.observe("host", k, _time.perf_counter() - t0)
         lanes.inc(k, route="cpu_batch")
-        return True, np.ones((k,), bool)
+        return (True, np.ones((k,), bool)), "cpu_batch"
     # refuted: localize per lane with the exact native single verify
     oks = np.fromiter(
         (_nat.verify(pubs[i].tobytes(), msgs[i, :int(lens[i])].tobytes(),
                      sigs[i].tobytes()) for i in range(k)), bool, k)
     lanes.inc(k, route="cpu")
-    return bool(oks.all()), oks
+    return (bool(oks.all()), oks), "cpu"
 
 
 class DeviceUnavailable(RuntimeError):

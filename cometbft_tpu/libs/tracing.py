@@ -13,11 +13,14 @@ verify micro-batches the vote scheduler ran inside the prevote span.
 Design constraints, in order:
 
 - **Disabled means free.**  Tracing is off unless
-  ``[instrumentation] tracing = true``.  ``event()`` returns on its
-  first instruction; ``span()`` returns one shared no-op context
-  manager (no per-call allocation); ``begin()`` returns None and
-  ``finish(None)`` is a no-op.  Hot paths may additionally guard with
-  :func:`is_enabled` to skip building attrs at all.
+  ``[instrumentation] tracing = true`` **or a JAX profiler session is
+  live in the process** (:func:`is_enabled`): whoever captures a device
+  profile finds the same interval in ``/dump_trace``, on the clock the
+  profile's host spans share.  Off, ``event()`` returns after one check;
+  ``span()`` returns one shared no-op context manager (no per-call
+  allocation); ``begin()`` returns None and ``finish(None)`` is a
+  no-op.  Hot paths may additionally guard with :func:`is_enabled` to
+  skip building attrs at all.
 - **Thread/asyncio-safe without locks on the emit path.**  Records are
   single ``deque.append`` calls (atomic under the GIL) of fully-built
   tuples, and ids come from ``itertools.count`` (also atomic) — writers
@@ -39,6 +42,7 @@ long-lived spans that cross handler boundaries (consensus steps) use
 from __future__ import annotations
 
 import itertools
+import sys
 import threading
 import time
 from collections import deque
@@ -57,10 +61,32 @@ _CONF_LOCK = threading.Lock()
 # attrs) — built whole, appended once (no partially-visible records)
 
 
+_TRACEME = None     # jax's TraceMe class, once jax is imported (False: none)
+
+
+def _profiling() -> bool:
+    """Is a JAX profiler session live in this process?  Resolved lazily
+    and only once ``jax`` is already imported, so this module imports
+    nothing heavy and a process without JAX reads False."""
+    global _TRACEME
+    tm = _TRACEME
+    if tm is None:
+        if "jax" not in sys.modules:
+            return False
+        try:
+            from jax._src.lib import _profiler
+            tm = _profiler.TraceMe
+        except (ImportError, AttributeError):   # a JAX that moved it
+            tm = False
+        _TRACEME = tm
+    return bool(tm) and tm.is_enabled()
+
+
 def is_enabled() -> bool:
-    """Fast gate for call sites that would otherwise build attrs dicts
+    """Recording: configured on, or a JAX profiler session is live.
+    Also the gate for call sites that would otherwise build attrs dicts
     or format values just to have ``event()`` drop them."""
-    return _ENABLED
+    return _ENABLED or _profiling()
 
 
 def configure(enabled: bool | None = None,
@@ -97,9 +123,10 @@ class _Open:
 
 def begin(sub: str, name: str, **attrs) -> "_Open | None":
     """Open a span that outlives the current stack frame (consensus
-    steps span many handler invocations).  Returns None when disabled —
+    steps span many handler invocations) or changes thread (its parent
+    is the opener's current span).  Returns None when disabled —
     :func:`finish` accepts it."""
-    if not _ENABLED:
+    if not (_ENABLED or _profiling()):
         return None
     o = _Open.__new__(_Open)
     o.id = next(_SEQ)
@@ -135,7 +162,7 @@ def finish(open_: "_Open | None", **extra) -> None:
 
 def event(sub: str, name: str, **attrs) -> None:
     """Fire-and-forget point event."""
-    if not _ENABLED:
+    if not (_ENABLED or _profiling()):
         return
     if _clock._CLOCK is None:
         wall, t = time.time_ns(), time.monotonic_ns()
@@ -147,9 +174,15 @@ def event(sub: str, name: str, **attrs) -> None:
 
 class _SpanCM:
     """Context-manager span: sets itself as the current parent for the
-    duration so nested ``span()``/``event()`` calls record ``parent``."""
+    duration so nested ``span()``/``event()`` calls record ``parent``.
+    While a profiler session is live it also writes itself into the
+    profile as a ``TraceMe`` named ``<sub>:<name>``, beside the device
+    ops on the profiler's own clock.  ``with span(...) as sp`` hands
+    the open span out (None when off) for attrs only known at the end;
+    a span opened with ``ok=True`` closes with ``ok=False`` when its
+    scope raises."""
 
-    __slots__ = ("_sub", "_name", "_attrs", "_open", "_tok")
+    __slots__ = ("_sub", "_name", "_attrs", "_open", "_tok", "_traceme")
 
     def __init__(self, sub, name, attrs):
         self._sub = sub
@@ -157,16 +190,24 @@ class _SpanCM:
         self._attrs = attrs
         self._open = None
         self._tok = None
+        self._traceme = None
 
     def __enter__(self):
         self._open = begin(self._sub, self._name, **self._attrs)
         if self._open is not None:
             self._tok = _CUR.set(self._open.id)
+            if _profiling():
+                self._traceme = _TRACEME(f"{self._sub}:{self._name}")
+                self._traceme.__enter__()
         return self._open
 
     def __exit__(self, *exc):
         if self._open is not None:
+            if self._traceme is not None:
+                self._traceme.__exit__(*exc)
             _CUR.reset(self._tok)
+            if exc[0] is not None and "ok" in self._open.attrs:
+                self._open.attrs["ok"] = False
             finish(self._open)
         return False
 
@@ -187,7 +228,7 @@ _NOOP = _NoopSpan()
 def span(sub: str, name: str, **attrs):
     """Context manager measuring one lexical scope.  Disabled tracing
     returns a shared no-op instance — zero per-call allocation."""
-    if not _ENABLED:
+    if not (_ENABLED or _profiling()):
         return _NOOP
     return _SpanCM(sub, name, attrs)
 
@@ -257,5 +298,7 @@ def dump(limit: int = 1000, sub: str | None = None,
 
 
 def stats() -> dict:
-    return {"enabled": _ENABLED, "ring_size": _MAXLEN,
-            "buffered": len(_RING)}
+    """``enabled`` is the configuration; ``recording`` also follows a
+    live profiler session."""
+    return {"enabled": _ENABLED, "recording": is_enabled(),
+            "ring_size": _MAXLEN, "buffered": len(_RING)}

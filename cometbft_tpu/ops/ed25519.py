@@ -72,6 +72,7 @@ BASE_NIELS = _base_niels_table()
 BASE_NIELS_T = np.transpose(BASE_NIELS, (1, 2, 0)).copy()
 
 
+@jax.named_scope("table_build")
 def _build_neg_a_table(neg_a: Ext) -> Cached:
     """Per-lane cached table of [j](-A), j = 0..15: components (16, 20, B).
 
@@ -149,7 +150,8 @@ def _verify_core(neg_a_tab, ok_a, rb, sb, blocks, active, n: int):
         acc = _g.add_cached(acc, _gather_cached(neg_a_tab, dh))
         return acc
 
-    acc = jax.lax.fori_loop(0, 64, window, _g.identity((n,)))
+    with jax.named_scope("ladder"):
+        acc = jax.lax.fori_loop(0, 64, window, _g.identity((n,)))
     acc = _g.add_cached(acc, _g.cache(_g.neg_ext(r_pt)))
     return ok_a & ok_r & ok_s & _g.is_identity(_g.mul_by_cofactor(acc))
 

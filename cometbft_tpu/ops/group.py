@@ -29,6 +29,7 @@ from __future__ import annotations
 from types import SimpleNamespace
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 
 
@@ -136,6 +137,7 @@ def make_group(f) -> SimpleNamespace:
                    f.mul(p.z2, INV2_C),
                    f.mul(p.t2d, INV2D_C))
 
+    @jax.named_scope("decompress")
     def decompress_zip215(enc):
         """ZIP-215 (permissive) point decoding: non-canonical y >= p
         accepted, x = 0 with sign bit 1 accepted, small/mixed-order
@@ -154,8 +156,6 @@ def make_group(f) -> SimpleNamespace:
         return Ext(x, y, f.bcast(ONE_C, sign.shape), f.mul(x, y)), ok
 
     def mul_by_cofactor(p: Ext) -> Ext:
-        import jax
-
         return jax.lax.fori_loop(0, 3, lambda _, q: dbl(q), p)
 
     def is_identity(p: Ext):

@@ -106,6 +106,7 @@ def _gather_all_windows(tab: Cached, digits) -> Cached:
     return Cached(*[one(c) for c in tab]), nw
 
 
+@jax.named_scope("rlc_tree")
 def _tree_reduce_lanes(ents: Cached, nw: int) -> Cached:
     """Binary tree of cached-coordinate additions over the lane-major
     (20, W*NW) sheet -> per-window sums (20, NW).
@@ -133,6 +134,7 @@ def _tree_reduce_lanes(ents: Cached, nw: int) -> Cached:
     return ents                                   # (20, NW)
 
 
+@jax.named_scope("rlc_sums")
 def _rlc_sums(neg_a_tab, ok_a, rb, sb, blocks, active, z10):
     """Per-window lane sums + the B-term scalar sum + the lane-ok
     verdict, for one (shard of a) batch.  Everything here is local to
@@ -167,6 +169,7 @@ def _rlc_sums(neg_a_tab, ok_a, rb, sb, blocks, active, z10):
     return sum_a, sum_r, zs_sum, lanes_ok
 
 
+@jax.named_scope("rlc_ladder")
 def _rlc_ladder(sum_a, sum_r, zs_sum):
     """The width-1 MSB-first ladder over precomputed per-window sums:
     64 x 4 doublings + one base-niels add + the A/R window sums, then

@@ -162,6 +162,7 @@ def _compress(state, block):
     return jnp.stack([hi + carry, lo], axis=-1)
 
 
+@jax.named_scope("sha512")
 def sha512_blocks(blocks, nblocks_active):
     """Batched SHA-512 over prepadded blocks.
 

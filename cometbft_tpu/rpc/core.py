@@ -738,7 +738,9 @@ async def dump_trace(env: Environment, limit=1000, sub=None,
     WAL fsyncs and verify micro-batches that ran inside them.
     ``sub=consensus`` keeps one subsystem; ``height=H`` keeps records
     stamped with that height.  Empty (with ``enabled: false``) unless
-    ``[instrumentation] tracing`` is on."""
+    ``[instrumentation] tracing`` is on; ``recording`` is also true
+    while a JAX profiler session is live in the process (the recorder
+    follows it, so a captured profile has its interval here)."""
     from ..libs import tracing
 
     lim = int(limit)
@@ -753,6 +755,7 @@ async def dump_trace(env: Environment, limit=1000, sub=None,
         int(height) if height is not None else None)
     return {
         "enabled": st["enabled"],
+        "recording": st["recording"],
         "ring_size": st["ring_size"],
         "buffered": st["buffered"],
         "records": records,

@@ -30,6 +30,7 @@ from __future__ import annotations
 from fractions import Fraction
 
 from ..crypto import batch as cryptobatch
+from ..libs import tracing
 from .commit import Commit
 from .validator_set import ValidatorSet
 
@@ -44,6 +45,23 @@ def set_default_backend(backend: str) -> None:
 
 def get_default_backend() -> str:
     return _DEFAULT_BACKEND
+
+
+def _verify_span(entry: str, items):
+    """The flight recorder's span of one entry call over ``items``
+    (``(block_id, height, commit)`` each): the commits and signatures
+    presented, stamped with the height (or the window's ``h_lo`` /
+    ``h_hi``) that ``/dump_trace?height=`` selects by; ``ok`` turns
+    False if the entry raises."""
+    if not tracing.is_enabled():
+        return tracing.span("types.validation", "verify")
+    heights = [h for _, h, _ in items]
+    where = {"height": heights[0]} if len(items) == 1 else \
+        {"h_lo": min(heights, default=0), "h_hi": max(heights, default=0)}
+    return tracing.span("types.validation", "verify", entry=entry,
+                        commits=len(items),
+                        lanes=sum(c.size() for _, _, c in items), ok=True,
+                        **where)
 
 
 class CommitVerificationError(Exception):
@@ -162,8 +180,6 @@ def _verify_aggregate(chain_id: str, vals: ValidatorSet, commit: Commit,
             if vi < 0 or val.pub_key.type() != "bls12_381":
                 return frozenset(), 0       # unattributable: contributes 0
             signers.append(vi)
-    from ..libs import tracing
-
     sp = tracing.begin("crypto.agg", "verify", height=commit.height,
                        lanes=len(lanes)) if tracing.is_enabled() else None
     ok = _blsagg.verify_commit_aggregate(
@@ -337,33 +353,37 @@ def _dense_verify(chain_id: str, vals: ValidatorSet, commit: Commit,
         # BLS member has dense() None anyway; this guards the malformed
         # all-Ed25519-commit-with-aggregate case into the strict loop)
         return False
-    dense = vals.dense()
-    cols = commit.dense_columns()
-    if dense is None or cols is None or not nat.available():
-        return False
-    pubs, powers = dense
-    flags, ts, sigmat = cols
-    if len(flags) != len(powers):
-        return False                   # size mismatch: let the loop raise
-    from .commit import BLOCK_ID_FLAG_ABSENT, BLOCK_ID_FLAG_COMMIT
-
-    commit_mask = flags == BLOCK_ID_FLAG_COMMIT
-    if count_all:
-        if verify_nil_sigs:
-            scope = np.nonzero(flags != BLOCK_ID_FLAG_ABSENT)[0]
-        else:
-            scope = np.nonzero(commit_mask)[0]
-        tally = int(powers[scope][commit_mask[scope]].sum()) if scope.size \
-            else 0
-    else:
-        scope, tally = _dense_light_scope(powers, flags, needed)
-    if scope.size:
-        built = _dense_build_rows(chain_id, commit, ts, flags, scope)
-        if built is None:
+    with tracing.span("types.validation", "rows", commits=1) as sp:
+        dense = vals.dense()
+        cols = commit.dense_columns()
+        if dense is None or cols is None or not nat.available():
             return False
-        msgs, lens = built
-        pubs_sel = np.ascontiguousarray(pubs[scope])
-        sigs_sel = np.ascontiguousarray(sigmat[scope])
+        pubs, powers = dense
+        flags, ts, sigmat = cols
+        if len(flags) != len(powers):
+            return False               # size mismatch: let the loop raise
+        from .commit import BLOCK_ID_FLAG_ABSENT, BLOCK_ID_FLAG_COMMIT
+
+        commit_mask = flags == BLOCK_ID_FLAG_COMMIT
+        if count_all:
+            if verify_nil_sigs:
+                scope = np.nonzero(flags != BLOCK_ID_FLAG_ABSENT)[0]
+            else:
+                scope = np.nonzero(commit_mask)[0]
+            tally = int(powers[scope][commit_mask[scope]].sum()) \
+                if scope.size else 0
+        else:
+            scope, tally = _dense_light_scope(powers, flags, needed)
+        if scope.size:
+            built = _dense_build_rows(chain_id, commit, ts, flags, scope)
+            if built is None:
+                return False
+            msgs, lens = built
+            pubs_sel = np.ascontiguousarray(pubs[scope])
+            sigs_sel = np.ascontiguousarray(sigmat[scope])
+        if sp is not None:
+            sp.attrs["lanes"] = int(scope.size)
+    if scope.size:
         from ..crypto import scheduler as _vsched
 
         if use_cache and _vsched.dense_cache_active():
@@ -411,47 +431,52 @@ def _dense_verify_trusting(chain_id: str, vals: ValidatorSet,
 
     if commit.has_aggregate():
         return False                   # aggregate lanes: loop path only
-    dense = vals.dense()
-    cols = commit.dense_columns()
-    if dense is None or cols is None or not nat.available():
-        return False
-    pubs, powers = dense
-    flags, ts, sigmat = cols
-    addrs = commit.dense_addresses()
-    aidx = vals.address_index()
-    seen: set[bytes] = set()
-    scope: list[int] = []            # commit-sig lanes to verify
-    rows: list[int] = []             # their rows in the trusted set
-    tally = 0
-    for i, addr in enumerate(addrs):
-        fl = int(flags[i])
-        # non-commit sigs are ignored BEFORE the lookup/dup bookkeeping,
-        # matching the reference's ignoreSig ordering in
-        # verifyCommitBatch (validation.go:243-266) — a NIL sig followed
-        # by a COMMIT sig from the same address is legal there
-        if fl != BLOCK_ID_FLAG_COMMIT:
-            continue
-        row = aidx.get(addr)
-        if row is None:
-            continue
-        if addr in seen:
-            raise ErrInvalidCommit(
-                f"duplicate validator {addr.hex()} in commit")
-        seen.add(addr)
-        scope.append(i)
-        rows.append(row)
-        tally += int(powers[row])
-        if not count_all and tally > needed:
-            break
-    if scope:
-        scope_arr = np.asarray(scope)
-        built = _dense_build_rows(chain_id, commit, ts, flags, scope_arr)
-        if built is None:
+    with tracing.span("types.validation", "rows", commits=1) as sp:
+        dense = vals.dense()
+        cols = commit.dense_columns()
+        if dense is None or cols is None or not nat.available():
             return False
-        msgs, lens = built
-        rows_arr = np.asarray(rows)
-        pubs_sel = np.ascontiguousarray(pubs[rows_arr])
-        sigs_sel = np.ascontiguousarray(sigmat[scope_arr])
+        pubs, powers = dense
+        flags, ts, sigmat = cols
+        addrs = commit.dense_addresses()
+        aidx = vals.address_index()
+        seen: set[bytes] = set()
+        scope: list[int] = []            # commit-sig lanes to verify
+        rows: list[int] = []             # their rows in the trusted set
+        tally = 0
+        for i, addr in enumerate(addrs):
+            fl = int(flags[i])
+            # non-commit sigs are ignored BEFORE the lookup/dup
+            # bookkeeping, matching the reference's ignoreSig ordering in
+            # verifyCommitBatch (validation.go:243-266) — a NIL sig
+            # followed by a COMMIT sig from the same address is legal
+            if fl != BLOCK_ID_FLAG_COMMIT:
+                continue
+            row = aidx.get(addr)
+            if row is None:
+                continue
+            if addr in seen:
+                raise ErrInvalidCommit(
+                    f"duplicate validator {addr.hex()} in commit")
+            seen.add(addr)
+            scope.append(i)
+            rows.append(row)
+            tally += int(powers[row])
+            if not count_all and tally > needed:
+                break
+        if scope:
+            scope_arr = np.asarray(scope)
+            built = _dense_build_rows(chain_id, commit, ts, flags,
+                                      scope_arr)
+            if built is None:
+                return False
+            msgs, lens = built
+            rows_arr = np.asarray(rows)
+            pubs_sel = np.ascontiguousarray(pubs[rows_arr])
+            sigs_sel = np.ascontiguousarray(sigmat[scope_arr])
+        if sp is not None:
+            sp.attrs["lanes"] = len(scope)
+    if scope:
         from ..crypto import scheduler as _vsched
 
         if use_cache and _vsched.dense_cache_active():
@@ -513,10 +538,12 @@ def VerifyCommit(chain_id: str, vals: ValidatorSet, block_id, height: int,
                  commit: Commit, backend: str | None = None) -> None:
     """All signatures verified; > 2/3 of total power must be for block_id
     (types/validation.go:28)."""
-    _check_commit_basics(vals, commit, height, block_id)
-    needed = vals.total_voting_power() * 2 // 3
-    _verify(chain_id, vals, commit, needed, count_all=True,
-            verify_nil_sigs=True, lookup_by_address=False, backend=backend)
+    with _verify_span("VerifyCommit", ((block_id, height, commit),)):
+        _check_commit_basics(vals, commit, height, block_id)
+        needed = vals.total_voting_power() * 2 // 3
+        _verify(chain_id, vals, commit, needed, count_all=True,
+                verify_nil_sigs=True, lookup_by_address=False,
+                backend=backend)
 
 
 def VerifyCommitLight(chain_id: str, vals: ValidatorSet, block_id,
@@ -530,11 +557,12 @@ def VerifyCommitLight(chain_id: str, vals: ValidatorSet, block_id,
     (light-client backfill, blocksync fallbacks) pass use_cache=False:
     with zero possible hits, the per-lane cache consult is pure
     overhead."""
-    _check_commit_basics(vals, commit, height, block_id)
-    needed = vals.total_voting_power() * 2 // 3
-    _verify(chain_id, vals, commit, needed, count_all=False,
-            verify_nil_sigs=False, lookup_by_address=False, backend=backend,
-            use_cache=use_cache)
+    with _verify_span("VerifyCommitLight", ((block_id, height, commit),)):
+        _check_commit_basics(vals, commit, height, block_id)
+        needed = vals.total_voting_power() * 2 // 3
+        _verify(chain_id, vals, commit, needed, count_all=False,
+                verify_nil_sigs=False, lookup_by_address=False,
+                backend=backend, use_cache=use_cache)
 
 
 def VerifyCommitLightAllSignatures(chain_id: str, vals: ValidatorSet,
@@ -542,11 +570,13 @@ def VerifyCommitLightAllSignatures(chain_id: str, vals: ValidatorSet,
                                    backend: str | None = None) -> None:
     """types/validation.go:96 (evidence path: no early exit, and no
     verified-signature cache — evidence rests on fresh verification)."""
-    _check_commit_basics(vals, commit, height, block_id)
-    needed = vals.total_voting_power() * 2 // 3
-    _verify(chain_id, vals, commit, needed, count_all=True,
-            verify_nil_sigs=False, lookup_by_address=False, backend=backend,
-            use_cache=False)
+    with _verify_span("VerifyCommitLightAllSignatures",
+                      ((block_id, height, commit),)):
+        _check_commit_basics(vals, commit, height, block_id)
+        needed = vals.total_voting_power() * 2 // 3
+        _verify(chain_id, vals, commit, needed, count_all=True,
+                verify_nil_sigs=False, lookup_by_address=False,
+                backend=backend, use_cache=False)
 
 
 def VerifyCommitLightTrusting(chain_id: str, vals: ValidatorSet,
@@ -562,9 +592,11 @@ def VerifyCommitLightTrusting(chain_id: str, vals: ValidatorSet,
         raise ValueError("trust level must be in (0, 1]")
     needed = (vals.total_voting_power() * trust_level.numerator
               // trust_level.denominator)
-    _verify(chain_id, vals, commit, needed, count_all=count_all,
-            verify_nil_sigs=False, lookup_by_address=True, backend=backend,
-            use_cache=use_cache)
+    with _verify_span("VerifyCommitLightTrusting",
+                      ((None, commit.height, commit),)):
+        _verify(chain_id, vals, commit, needed, count_all=count_all,
+                verify_nil_sigs=False, lookup_by_address=True,
+                backend=backend, use_cache=use_cache)
 
 
 class ErrBatchItemInvalid(CommitVerificationError):
@@ -611,14 +643,24 @@ def verify_commits_light_batched(chain_id: str, vals: ValidatorSet,
     failure — earlier items were NOT signature-checked and need their
     own verification pass before being trusted.
     """
+    with _verify_span("verify_commits_light_batched", items):
+        return _verify_commits_light_batched(
+            chain_id, vals, items, backend or _DEFAULT_BACKEND, patient,
+            use_cache)
+
+
+def _verify_commits_light_batched(chain_id: str, vals: ValidatorSet,
+                                  items: list, backend: str, patient: bool,
+                                  use_cache: bool) -> int:
+    """:func:`verify_commits_light_batched` proper: the dense core, else
+    the per-lane loop."""
     from ..crypto import scheduler as _vsched
 
-    n = _dense_verify_commits_batched(chain_id, vals, items,
-                                      backend or _DEFAULT_BACKEND,
+    n = _dense_verify_commits_batched(chain_id, vals, items, backend,
                                       patient=patient, use_cache=use_cache)
     if n is not None:
         return n
-    bv = cryptobatch.create_batch_verifier(backend or _DEFAULT_BACKEND)
+    bv = cryptobatch.create_batch_verifier(backend)
     lanes: list[tuple[int, int]] = []      # (item idx, commit-sig idx)
     seeds: list[tuple] = []
     cache_on = use_cache and _vsched.cache_active()
@@ -691,46 +733,50 @@ def _dense_verify_commits_batched(chain_id: str, vals: ValidatorSet,
         return None                    # aggregate lanes: loop path only
     pubs, powers = dense
     needed = vals.total_voting_power() * 2 // 3
-    sel_pubs, sel_sigs, sel_msgs, sel_lens = [], [], [], []
-    sel_scope = []
-    lanes: list[tuple[int, int]] = []
-    stride = 0
-    for k, (block_id, height, commit) in enumerate(items):
-        try:
-            _check_commit_basics(vals, commit, height, block_id)
-        except CommitVerificationError as e:
-            raise ErrBatchItemInvalid(k, height, e) from e
-        cols = commit.dense_columns()
-        if cols is None:
-            return None
-        flags, ts, sigmat = cols
-        scope, tally = _dense_light_scope(powers, flags, needed)
-        if tally <= needed:
-            raise ErrBatchItemInvalid(
-                k, height,
-                ErrNotEnoughVotingPower(f"tallied {tally} <= {needed}"))
-        built = _dense_build_rows(chain_id, commit, ts, flags, scope)
-        if built is None:
-            return None
-        msgs, lens = built
-        sel_pubs.append(pubs[scope])
-        sel_sigs.append(sigmat[scope])
-        sel_msgs.append(msgs)
-        sel_lens.append(lens)
-        sel_scope.append(scope)
-        stride = max(stride, msgs.shape[1])
-        lanes.extend((k, int(i)) for i in scope)
-    if not lanes:
-        return 0
-    # strides are equal in practice (same chain_id; fixed-width height);
-    # pad defensively if a template ever differs
-    sel_msgs = [m if m.shape[1] == stride else np.pad(
-        m, ((0, 0), (0, stride - m.shape[1]))) for m in sel_msgs]
-    pubs_all = np.ascontiguousarray(np.concatenate(sel_pubs))
-    sigs_all = np.ascontiguousarray(np.concatenate(sel_sigs))
-    msgs_all = np.ascontiguousarray(np.concatenate(sel_msgs))
-    lens_all = np.concatenate(sel_lens)
-    scope_all = np.concatenate(sel_scope)
+    with tracing.span("types.validation", "rows",
+                      commits=len(items)) as sp:
+        sel_pubs, sel_sigs, sel_msgs, sel_lens = [], [], [], []
+        sel_scope = []
+        lanes: list[tuple[int, int]] = []
+        stride = 0
+        for k, (block_id, height, commit) in enumerate(items):
+            try:
+                _check_commit_basics(vals, commit, height, block_id)
+            except CommitVerificationError as e:
+                raise ErrBatchItemInvalid(k, height, e) from e
+            cols = commit.dense_columns()
+            if cols is None:
+                return None
+            flags, ts, sigmat = cols
+            scope, tally = _dense_light_scope(powers, flags, needed)
+            if tally <= needed:
+                raise ErrBatchItemInvalid(
+                    k, height,
+                    ErrNotEnoughVotingPower(f"tallied {tally} <= {needed}"))
+            built = _dense_build_rows(chain_id, commit, ts, flags, scope)
+            if built is None:
+                return None
+            msgs, lens = built
+            sel_pubs.append(pubs[scope])
+            sel_sigs.append(sigmat[scope])
+            sel_msgs.append(msgs)
+            sel_lens.append(lens)
+            sel_scope.append(scope)
+            stride = max(stride, msgs.shape[1])
+            lanes.extend((k, int(i)) for i in scope)
+        if sp is not None:
+            sp.attrs["lanes"] = len(lanes)
+        if not lanes:
+            return 0
+        # strides are equal in practice (same chain_id; fixed-width height);
+        # pad defensively if a template ever differs
+        sel_msgs = [m if m.shape[1] == stride else np.pad(
+            m, ((0, 0), (0, stride - m.shape[1]))) for m in sel_msgs]
+        pubs_all = np.ascontiguousarray(np.concatenate(sel_pubs))
+        sigs_all = np.ascontiguousarray(np.concatenate(sel_sigs))
+        msgs_all = np.ascontiguousarray(np.concatenate(sel_msgs))
+        lens_all = np.concatenate(sel_lens)
+        scope_all = np.concatenate(sel_scope)
     keys = None
     if use_cache and _vsched.cache_active():
         # per-lane dedup-cache consult (same key material as the single-

@@ -26,6 +26,7 @@ enable_compile_cache()
 # kernel tests must exercise the device code path even when a cold compile
 # outlasts the production watchdog (which would silently host-fallback)
 from cometbft_tpu.crypto import batch as _batch  # noqa: E402
+from cometbft_tpu.crypto import plan as _plan  # noqa: E402
 
 _batch.set_device_wait(900)
 
@@ -78,3 +79,16 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers", "timeout(seconds): per-test wall-clock limit "
         "(enforced by conftest SIGALRM)")
+
+
+@pytest.fixture(autouse=True)
+def _dispatch_state_restored():
+    """``Node.start`` writes the device plan (``min_device_lanes = 64``)
+    and the 2 s device wait process-wide, and the benchmark's rehearsals
+    set their own wait.  Left behind in an xdist worker, they sent a later
+    file's small batches to the host: the two rehearsal cases that failed
+    under ``--dist loadfile`` and passed alone.  Every test ends on the
+    state this file set up."""
+    yield
+    _plan.reset()
+    _batch.set_device_wait(900)

@@ -189,6 +189,175 @@ def test_disabled_mode_is_noop_and_allocation_free():
     assert tracing.dump() == []
 
 
+def test_off_path_of_a_seam_span_site_allocates_nothing():
+    """A span site as ``crypto/batch`` writes them: attrs as keywords,
+    the open span taken for an attr only known at the end."""
+    import jax  # noqa: F401  (the session check resolves once jax is in)
+
+    assert not tracing.is_enabled()
+
+    def site(lanes, bucket):
+        with tracing.span("crypto.seam", "pack", lanes=lanes,
+                          bucket=bucket) as sp:
+            if sp is not None:
+                sp.attrs["blocks"] = 2
+        tracing.finish(tracing.begin("crypto.seam", "queue", patient=False))
+
+    for _ in range(256):
+        site(101, 256)
+    before = sys.getallocatedblocks()
+    for _ in range(4096):
+        site(101, 256)
+    assert sys.getallocatedblocks() - before <= 8
+    assert tracing.snapshot() == []
+
+
+# ------------------------------------- following a live profiler session
+
+
+def _profiler_session():
+    import jax
+    from jax._src.lib import _profiler
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    return _profiler.ProfilerSession(opts)
+
+
+def test_recorder_follows_a_live_profiler_session():
+    import jax
+
+    assert not tracing.is_enabled() and tracing.begin("t", "x") is None
+    session = _profiler_session()
+    try:
+        assert tracing.is_enabled() and tracing.stats()["recording"]
+        assert not tracing.stats()["enabled"]       # the configuration
+        with tracing.span("crypto.seam", "pack", lanes=3):
+            tracing.event("t", "inside")
+    finally:
+        xspace = session.stop()
+    assert not tracing.is_enabled()
+    with tracing.span("crypto.seam", "pack"):       # off again: not kept
+        pass
+    recs = tracing.dump()
+    assert [(r["sub"], r["name"]) for r in recs] == [
+        ("t", "inside"), ("crypto.seam", "pack")]
+    assert recs[0]["parent"] == recs[1]["id"]
+    # the span also wrote itself into the profile, as <sub>:<name>
+    names = {e.name for p in
+             jax.profiler.ProfileData.from_serialized_xspace(xspace).planes
+             for line in p.lines for e in line.events}
+    assert "crypto.seam:pack" in names
+    assert not any(n.startswith("bench:") for n in names)
+
+
+# ------------------------------------------- spans of the backend seam
+
+
+def test_owner_thread_spans_name_the_callers_span_as_parent():
+    from cometbft_tpu.crypto import batch as B
+
+    tracing.configure(enabled=True)
+
+    def on_owner():
+        tracing.event("t", "on_owner")
+        with tracing.span("t", "owner_span"):
+            pass
+        return threading.current_thread().name
+
+    with tracing.span("t", "caller") as caller:
+        assert B._device_call(on_owner).startswith("tpu-verify")
+    by_name = {r["name"]: r for r in tracing.dump()}
+    for name in ("queue", "on_owner", "owner_span"):
+        assert by_name[name]["parent"] == caller.id, name
+    assert by_name["queue"]["attrs"] == {"patient": False, "abandoned": False}
+    assert by_name["queue"]["end_ns"] <= by_name["on_owner"]["start_ns"]
+    # the caller's context is back to what it was
+    tracing.event("t", "after")
+    assert tracing.dump()[-1]["parent"] == 0
+
+
+def _children(recs, parent):
+    return sorted((r for r in recs if r["parent"] == parent["id"]),
+                  key=lambda r: r["start_ns"])
+
+
+def test_verify_dense_spans_at_the_16_lane_bucket(monkeypatch):
+    """One commit through the ``jax`` backend: the entry, ``rows`` and
+    ``verify_dense`` with exactly the seam's spans beneath it, each with
+    its counts; a refuted batch verdict adds a ``gather`` launch."""
+    import numpy as np
+
+    from cometbft_tpu.crypto import batch as B
+    from cometbft_tpu.crypto import plan as P
+    from cometbft_tpu.testing import make_light_chain
+    from cometbft_tpu.types import validation as V
+
+    lb = make_light_chain(1, n_vals=4)[0]
+
+    def light():
+        V.VerifyCommitLight("light-chain", lb.validators, lb.commit.block_id,
+                            lb.height, lb.commit, backend="jax",
+                            use_cache=False)
+
+    light()                     # the table of this valset is built here
+    assert tracing.snapshot() == []         # ... with the recorder off
+    tracing.configure(enabled=True)
+    light()
+    recs = tracing.dump()
+    (entry,) = [r for r in recs if r["name"] == "verify"]
+    assert entry["sub"] == "types.validation" and entry["attrs"] == {
+        "entry": "VerifyCommitLight", "commits": 1, "lanes": 4, "ok": True,
+        "height": lb.height}
+    rows, dense = _children(recs, entry)
+    assert (rows["name"], rows["attrs"]) == ("rows", {"commits": 1, "lanes": 3})
+    assert (dense["sub"], dense["name"]) == ("crypto.seam", "verify_dense")
+    assert dense["attrs"] == {"lanes": 3, "patient": False, "route": "device"}
+    seam = _children(recs, dense)
+    assert [r["name"] for r in seam] == ["queue", "tables", "pack", "launch",
+                                         "readback"]
+    assert all(r["sub"] == "crypto.seam" for r in seam)
+    attrs = {r["name"]: r["attrs"] for r in seam}
+    assert attrs["tables"] == {"hit": True, "rows": 4}
+    assert attrs["pack"] == {"lanes": 3, "bucket": 16, "blocks": 2}
+    assert attrs["launch"] == {"kind": "gather", "lanes": 3, "bucket": 16}
+    assert attrs["readback"] == {"kind": "gather", "ok": True}
+    assert len(recs) == 8                   # nothing else was recorded
+    assert tracing.dump(height=lb.height) == [entry]
+
+    # a placement to a pinned chip is a ``put`` span with its bytes (the
+    # unpinned route above hands numpy arrays to the launch itself)
+    import jax
+
+    tracing.clear()
+    B._put((np.zeros((16, 32), np.int32), np.zeros((16,), np.int32)),
+           jax.devices()[0])
+    (put,) = tracing.dump()
+    assert (put["name"], put["attrs"]) == ("put", {"bytes": 16 * 33 * 4})
+
+    # the batch verdict refuted: the per-lane kernel localizes, and the
+    # entry's span closes not ok when the commit is refused
+    tracing.clear()
+    monkeypatch.setattr(B, "_compiled_rlc_gather",
+                        lambda: lambda *a: np.bool_(False))
+    P.configure(rlc_min_lanes=1)
+    bad = make_light_chain(1, n_vals=4)[0].commit
+    bad.signatures[1].signature = bytes(64)
+    with pytest.raises(V.ErrInvalidSignature):
+        V.VerifyCommit("light-chain", lb.validators, bad.block_id, lb.height,
+                       bad, backend="jax")
+    recs = tracing.dump()
+    assert [(r["attrs"]["kind"], r["attrs"]["lanes"], r["attrs"]["bucket"])
+            for r in recs if r["name"] == "launch"] == [
+        ("rlc_gather", 4, 16), ("gather", 4, 16)]
+    assert [r["attrs"]["ok"] for r in recs if r["name"] == "readback"] \
+        == [False, False]
+    (entry,) = [r for r in recs if r["name"] == "verify"]
+    assert entry["attrs"]["entry"] == "VerifyCommit" \
+        and entry["attrs"]["ok"] is False
+
+
 # ------------------------------------------------------- RPC round-trip
 
 
