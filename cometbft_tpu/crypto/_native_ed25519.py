@@ -7,7 +7,8 @@ SURVEY §2.9-1 requires to be native, never a Python stand-in.  The batch
 entry verifies n signatures as ONE Pippenger multiscalar multiplication,
 ~5x a single-verify loop at commit scale.  It also hosts the native
 canonical vote sign-bytes builder (SURVEY §2.9-4) used by the dense
-VerifyCommit fast path.
+VerifyCommit fast path, and the last step of the device's RLC verdict
+(``rlc_fold``: the fold of the per-window sums the chip returns).
 
 Degrades gracefully: if the on-demand g++ build fails, every function
 returns None and callers keep their pure-host path.
@@ -133,6 +134,35 @@ def batch_verify_dense(pubs, sigs, msgs, lens) -> bool | None:
         sigs.ctypes.data_as(ctypes.c_char_p),
         msgs.ctypes.data_as(ctypes.c_char_p),
         lens64.ctypes.data_as(_U64P), n, os.urandom(32), msgs.shape[1]))
+
+
+@functools.cache
+def _rlc_fold_fn():
+    """``ed25519_rlc_fold`` through ``ctypes.PyDLL``: the GIL is HELD for
+    the call.  It runs ~0.15 ms on the device-owner thread; dropping the
+    GIL for it (``CDLL``) hands the interpreter to a caller thread that is
+    building its next window's rows in Python, and taking it back then
+    waits out the 5 ms switch interval: 5.5 ms a fold against 0.19 ms,
+    measured with one busy thread beside it (``PERF.md`` §6, PR 27)."""
+    from ..native import lib_path
+
+    fn = ctypes.PyDLL(lib_path("ed25519")).ed25519_rlc_fold
+    fn.restype = ctypes.c_int
+    fn.argtypes = [ctypes.c_void_p]
+    return fn
+
+
+def rlc_fold(packed) -> bool | None:
+    """The group equation over a device RLC program's packed sums
+    (``crypto/rlc_finish.py`` has the layout and checks the shape):
+    a C-contiguous int32 array of non-negative limbs.  None when the
+    lib is unavailable."""
+    if _lib() is None:
+        return None
+    if packed.dtype.str != "<i4" or not packed.flags.c_contiguous \
+            or packed.shape != (20, 386):
+        raise ValueError("rlc_fold wants a C-contiguous (20, 386) int32")
+    return bool(_rlc_fold_fn()(packed.ctypes.data))
 
 
 def build_vote_sign_bytes(pre_commit: bytes, pre_nil: bytes, post: bytes,

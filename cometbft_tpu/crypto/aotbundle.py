@@ -42,7 +42,9 @@ import time
 from . import plan as _plan
 
 _MAGIC = "cmt-aot"
-_FORMAT = 1
+_FORMAT = 2          # 2: the RLC programs return their packed window
+#   sums (crypto/rlc_finish.py), not a boolean: a format-1 bundle must
+#   never be loaded against the caller that folds them
 
 _LOADED: dict[str, object] = {}      # bucket key -> loaded executable
 _INFO: dict = {"status": "absent", "buckets": {}}

@@ -38,6 +38,7 @@ import numpy as np
 
 from ..libs import tracing
 from . import plan as _plan
+from . import rlc_finish
 from .keys import ED25519_KEY_TYPE, PubKey, verify_ed25519_zip215
 
 # Bucket tables live in the declarative device plan (crypto/plan.py)
@@ -594,13 +595,21 @@ def _put(tree, place):
 def _run(kind: str, fn, args: tuple, lanes: int, bb: int) -> np.ndarray:
     """One compiled program: the ``launch`` span ends when the call
     returns its (unready) result, the ``readback`` span when the verdict
-    is on the host."""
+    is on the host.  The RLC kinds return their per-window sums, and the
+    verdict is the host's fold of them (``crypto/rlc_finish.py``), a
+    ``finish`` span inside ``readback``."""
     t0 = time.perf_counter()
     with tracing.span("crypto.seam", "launch", kind=kind, lanes=lanes,
                       bucket=bb):
         out = fn(*args)
     with tracing.span("crypto.seam", "readback", kind=kind) as sp:
         out = np.asarray(out)
+        if kind.startswith("rlc"):
+            with tracing.span("crypto.seam", "finish") as fsp:
+                ok, native = rlc_finish.finish(out)
+                if fsp is not None:
+                    fsp.attrs.update(native=native, ok=ok)
+            out = np.bool_(ok)
         if sp is not None:
             sp.attrs["ok"] = bool(out.all())
     _note_dispatch(kind, bb, time.perf_counter() - t0)

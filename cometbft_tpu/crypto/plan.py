@@ -155,7 +155,8 @@ KERNEL_SHARDINGS = {
     # verify_padded(pub, r, s, msgs, active) -> ok[lane]
     "verify": {"in": ("lane",) * 5, "out": "lane",
                "donate": (0, 1, 2, 3, 4)},
-    # rlc(pub, r, s, msgs, active, z10) -> scalar verdict
+    # rlc(pub, r, s, msgs, active, z10) -> the packed window sums, one
+    # replicated (20, 386) array (crypto/rlc_finish.py folds them)
     "rlc": {"in": ("lane",) * 6, "out": "repl", "donate": (0, 1, 2, 3, 4)},
     # gather(tables..., ok_active, idx, r, s, msgs, active) -> ok[lane]
     # (the Cached table tuple + precomputed ok row are replicated; the

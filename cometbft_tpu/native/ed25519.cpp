@@ -16,6 +16,7 @@
 // Exported C ABI (ctypes, see crypto/_native_ed25519.py):
 //   ed25519_verify(pub, sig, msg, len)            -> 1/0
 //   ed25519_batch_verify(pubs, sigs, msgs, lens, n, seed32) -> 1/0
+//   ed25519_rlc_fold(packed)   the device RLC programs' last step -> 1/0
 
 #include <cstdint>
 #include <cstring>
@@ -1182,6 +1183,93 @@ int ed25519_batch_verify(const u8* pubs, const u8* sigs, const u8* msgs,
     ge_double(T, T);
     ge_double(T, T);
     return ge_is_identity(T) ? 1 : 0;
+}
+
+}  // extern "C"
+
+// ------------------------------------------------- RLC fold (device sums)
+// The device's RLC programs (ops/rlc.py) return their per-window lane sums
+// and the host finishes the verdict (crypto/rlc_finish.py has the layout
+// and the reasons): packed is (20, 386) int32, row i holding limb i (radix
+// 2^13) of 386 field elements / scalars, one per column.
+
+static const int RLC_NL = 20, RLC_COLS = 386;
+static const int RLC_NW_A = 64, RLC_NW_R = 32;
+static const int RLC_A0 = 0, RLC_R0 = 256, RLC_ZS = 384;
+
+// column -> little-endian 64-bit words of sum(limb_i << 13 i); limbs are
+// any non-negative int32 (the chip leaves them loose), so below 2^279
+static void rlc_words(u64 w[8], const int32_t* packed, int col) {
+    for (int i = 0; i < 8; i++) w[i] = 0;
+    for (int i = 0; i < RLC_NL; i++) {
+        int bit = 13 * i, k = bit >> 6;
+        u128 v = (u128)(u64)(uint32_t)packed[i * RLC_COLS + col]
+                 << (bit & 63);
+        for (; v; k++) {
+            v += w[k];
+            w[k] = (u64)v;
+            v >>= 64;
+        }
+    }
+}
+
+static void rlc_fe(fe& r, const int32_t* packed, int col) {
+    u64 w[8];
+    rlc_words(w, packed, col);
+    r.v[0] = w[0] & MASK51;
+    r.v[1] = ((w[0] >> 51) | (w[1] << 13)) & MASK51;
+    r.v[2] = ((w[1] >> 38) | (w[2] << 26)) & MASK51;
+    r.v[3] = ((w[2] >> 25) | (w[3] << 39)) & MASK51;
+    r.v[4] = (w[3] >> 12) & MASK51;
+    r.v[0] += 19 * ((w[3] >> 63) | (w[4] << 1));    // 2^255 = 19: < 2^25
+    fe_carry(r);
+}
+
+// p + q for q in cached coordinates (Y+X, Y-X, 2Z, 2dT), window w of nw
+static void rlc_add_cached(ge& r, const ge& p, const int32_t* packed,
+                           int base, int nw, int w) {
+    fe ypx, ymx, z2, t2d, a, b, c, d, e, f, g, h, t;
+    rlc_fe(ypx, packed, base + w);
+    rlc_fe(ymx, packed, base + nw + w);
+    rlc_fe(z2, packed, base + 2 * nw + w);
+    rlc_fe(t2d, packed, base + 3 * nw + w);
+    fe_sub(t, p.Y, p.X);
+    fe_mul(a, t, ymx);
+    fe_add(t, p.Y, p.X);
+    fe_mul(b, t, ypx);
+    fe_mul(c, p.T, t2d);
+    fe_mul(d, p.Z, z2);
+    fe_sub(e, b, a);
+    fe_sub(f, d, c);
+    fe_add(g, d, c);
+    fe_add(h, b, a);
+    fe_mul(r.X, e, f);
+    fe_mul(r.Y, g, h);
+    fe_mul(r.T, e, h);
+    fe_mul(r.Z, f, g);
+}
+
+extern "C" {
+
+// [8]([zs]B + sum_w 16^w (S_A[w] + S_R[w])) == identity, MSB-first Horner
+// over the 64 windows; S_R has the lower 32 (128-bit z).  1 / 0.
+int ed25519_rlc_fold(const int32_t* packed) {
+    ge acc = GE_ID;
+    for (int w = RLC_NW_A - 1; w >= 0; w--) {
+        for (int i = 0; i < 4; i++) ge_double(acc, acc);
+        rlc_add_cached(acc, acc, packed, RLC_A0, RLC_NW_A, w);
+        if (w < RLC_NW_R)
+            rlc_add_cached(acc, acc, packed, RLC_R0, RLC_NW_R, w);
+    }
+    u64 zw[8];
+    rlc_words(zw, packed, RLC_ZS);
+    sc zs;
+    sc_reduce512(zs, zw);           // [kL]B is the identity: B has order L
+    ge zsB;
+    ge_scalarmul(zsB, zs, BASE_POINT);
+    ge_add(acc, acc, zsB);
+    for (int i = 0; i < 3; i++) ge_double(acc, acc);
+    return ge_is_identity(acc) ? 1 : 0;
 }
 
 }  // extern "C"

@@ -22,10 +22,15 @@ for the TPU's vector units:
   log2(B) levels of halving-width vector adds — total group-op work
   ~B per window instead of ~6B for the per-lane ladder, and every
   level is a dense vector op over the limb-major lane axis.
-- The B term needs no tree: Σzᵢsᵢ mod L is a cheap mod-L sum and one
-  scalar walks the constant [j]B niels table.
-- zᵢ is 128 bits, so the R tree only runs for the lower 32 windows
-  (a branch on the loop counter — compile-time-friendly ``lax.cond``).
+- The B term needs no tree: Σzᵢsᵢ mod L is a cheap mod-L sum, one
+  scalar for the whole batch.
+- zᵢ is 128 bits, so the R tree only runs for the lower 32 windows.
+- The programs END at the per-window sums: what is left, the MSB-first
+  fold ``Σ_w 16^w·S_w`` with its 252 doublings one after another, is one
+  lane wide whatever the batch (62 ms a dispatch on a v5e, at 256 lanes
+  or at 4,096), so the host does it in ~0.1 ms with its 64-bit field
+  arithmetic (``crypto/rlc_finish.py``).  The chip returns ONE int32
+  array: the window sums, Σzᵢsᵢ and the lane-ok bit.
 
 Soundness: per-lane defects Dᵢ = sᵢB - hᵢAᵢ - Rᵢ of VALID signatures
 are torsion (killed by the cofactor), so any zᵢ accept; a batch with a
@@ -48,9 +53,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..crypto import rlc_finish
 from . import fe, scalar, sha512
-from .ed25519 import BASE_NIELS_T, _build_neg_a_table, _g
-from .group import Cached, Niels
+from .ed25519 import _build_neg_a_table, _g
+from .group import Cached
 
 __all__ = ["verify_batch_rlc", "verify_batch_rlc_gather",
            "host_rlc_coeffs"]
@@ -139,8 +145,8 @@ def _rlc_sums(neg_a_tab, ok_a, rb, sb, blocks, active, z10):
     """Per-window lane sums + the B-term scalar sum + the lane-ok
     verdict, for one (shard of a) batch.  Everything here is local to
     the lanes it sees — the sharded dispatch runs this per device and
-    combines the outputs, the single-device path feeds them straight to
-    :func:`_rlc_ladder`."""
+    combines the outputs, the single-device path packs them for the
+    host's fold."""
     r_pt, ok_r = _g.decompress_zip215(jnp.transpose(rb))
     neg_r_tab = _build_neg_a_table(_g.neg_ext(r_pt))
 
@@ -169,51 +175,34 @@ def _rlc_sums(neg_a_tab, ok_a, rb, sb, blocks, active, z10):
     return sum_a, sum_r, zs_sum, lanes_ok
 
 
-@jax.named_scope("rlc_ladder")
-def _rlc_ladder(sum_a, sum_r, zs_sum):
-    """The width-1 MSB-first ladder over precomputed per-window sums:
-    64 x 4 doublings + one base-niels add + the A/R window sums, then
-    the cofactored identity check."""
-    sum_dig = scalar.nibbles(zs_sum)             # (64,)
-    base_ents = jnp.take(jnp.asarray(BASE_NIELS_T), sum_dig,
-                         axis=2)                 # (3, 20, 64)
-
-    def window(i, acc):
-        w = 63 - i
-        acc = jax.lax.fori_loop(0, 4, lambda _, a: _g.dbl(a), acc)
-        be = jax.lax.dynamic_slice_in_dim(base_ents, w, 1, axis=2)
-        acc = _g.add_niels(acc, Niels(be[0], be[1], be[2]))
-        sa = Cached(*[jax.lax.dynamic_slice_in_dim(c, w, 1, axis=1)
-                      for c in sum_a])
-        acc = _g.add_cached(acc, sa)
-
-        def with_r(a):
-            # w < 32 in this branch; the traced w>=32 index clamps
-            # harmlessly (branch never executes there)
-            sr = Cached(*[jax.lax.dynamic_slice_in_dim(c, w, 1, axis=1)
-                          for c in sum_r])
-            return _g.add_cached(a, sr)
-
-        return jax.lax.cond(w < 32, with_r, lambda a: a, acc)
-
-    acc = jax.lax.fori_loop(0, 64, window, _g.identity((1,)))
-    return _g.is_identity(_g.mul_by_cofactor(acc))[0]
+def _pack_sums(sum_a, sum_r, zs_sum, lanes_ok):
+    """One int32 (20, 386) array, ``crypto/rlc_finish.py``'s layout: the
+    cached coordinates of the window sums side by side, then ``zs_sum``
+    and ``lanes_ok`` as a column each.  The limbs stay in ``fe_lm``'s
+    loose form: the host's fold takes any non-negative limbs, so the
+    chip spends nothing on a freeze."""
+    ok_col = jnp.broadcast_to(lanes_ok.astype(jnp.int32), (fe.NLIMBS, 1))
+    out = jnp.concatenate(
+        [*sum_a, *sum_r, zs_sum.astype(jnp.int32)[:, None], ok_col], axis=1)
+    assert out.shape == rlc_finish.SHAPE        # one layout, stated there
+    return out
 
 
 def _rlc_core(neg_a_tab, ok_a, rb, sb, blocks, active, z10):
-    """Shared RLC ladder over per-lane [j](-A) cached tables."""
-    sum_a, sum_r, zs_sum, lanes_ok = _rlc_sums(
-        neg_a_tab, ok_a, rb, sb, blocks, active, z10)
-    return lanes_ok & _rlc_ladder(sum_a, sum_r, zs_sum)
+    """Shared RLC sums over per-lane [j](-A) cached tables, packed."""
+    return _pack_sums(*_rlc_sums(neg_a_tab, ok_a, rb, sb, blocks, active,
+                                 z10))
 
 
 def verify_batch_rlc(pub, rb, sb, blocks, active, z10):
-    """One-shot RLC verdict for a padded batch.
+    """The RLC sums of a padded batch, for the host to finish.
 
     pub/rb/sb (B, 32) int32 bytes; blocks/active as
     ``ed25519.verify_padded``; z10 (B, 10) int32 coefficient limbs
-    (``host_rlc_coeffs`` — 0 on padding lanes).  Returns a scalar bool:
-    True iff every active lane verifies (up to the 2⁻¹²⁸ RLC bound).
+    (``host_rlc_coeffs`` — 0 on padding lanes).  Returns the packed
+    (20, 386) int32 array of :func:`_pack_sums`;
+    ``crypto.rlc_finish.finish`` turns it into the verdict: True iff
+    every active lane verifies (up to the 2⁻¹²⁸ RLC bound).
     """
     from .ed25519 import prepare_pubkey_tables
 
@@ -222,18 +211,18 @@ def verify_batch_rlc(pub, rb, sb, blocks, active, z10):
 
 
 def verify_batch_rlc_gather(tab, ok_a, idx, rb, sb, blocks, active, z10):
-    """RLC verdict through a CACHED whole-validator-set table
+    """RLC sums through a CACHED whole-validator-set table
     (``ed25519.prepare_pubkey_tables`` output): the steady-state commit
     path — A decompression and table building amortize across commits,
-    the doublings amortize across lanes, so per-commit device work is
-    the gathers, two trees, and one width-1 ladder."""
+    the doublings amortize across lanes and leave the chip, so
+    per-commit device work is the gathers and two trees."""
     lane_tab = Cached(*[jnp.take(c, idx, axis=2) for c in tab])
     lane_ok = jnp.take(ok_a, idx, axis=0)
     return _rlc_core(lane_tab, lane_ok, rb, sb, blocks, active, z10)
 
 
 def make_verify_batch_rlc_sharded(mesh, gather: bool = False):
-    """RLC verdict sharded over the lane axis of ``mesh``.
+    """RLC sums sharded over the lane axis of ``mesh``.
 
     The tree reduce is group addition, not an elementwise sum, so the
     lane tree cannot simply ``psum``: instead each device runs
@@ -241,7 +230,8 @@ def make_verify_batch_rlc_sharded(mesh, gather: bool = False):
     gathers and the local reduction tree all stay collective-free), and
     only the per-device PARTIAL per-window sums — cached coordinates,
     (20, 96) per device — cross the interconnect, where a replicated
-    tree of ``add_cc`` folds them before the single width-1 ladder.
+    chain of ``add_cc`` folds them into the one packed array the host
+    finishes.
     Cross-chip traffic is therefore O(windows) points per verdict,
     independent of batch size — the reduction the single-device gate at
     ``crypto/batch.py`` used to forbid.
@@ -301,7 +291,7 @@ def make_verify_batch_rlc_sharded(mesh, gather: bool = False):
             sum_a = _g.add_cc(sum_a, Cached(*[c[d] for c in sa_stk]))
             sum_r = _g.add_cc(sum_r, Cached(*[c[d] for c in sr_stk]))
         zs_sum = scalar.sum_mod_l(zs_stk, axis=0)
-        return jnp.all(ok_stk) & _rlc_ladder(sum_a, sum_r, zs_sum)
+        return _pack_sums(sum_a, sum_r, zs_sum, jnp.all(ok_stk))
 
     if gather:
         def fn(tab, ok_a, idx, rb, sb, blocks, active, z10):

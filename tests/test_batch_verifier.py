@@ -464,7 +464,11 @@ def _fake_verdict_fns(monkeypatch, rlc_verdict=True):
         return make
 
     ones = lambda *a: np.ones(np.asarray(a[0]).shape[0], bool)  # noqa: E731
-    verdict = lambda *a: np.bool_(rlc_verdict)                  # noqa: E731
+    from cometbft_tpu.crypto import rlc_finish
+
+    # an RLC program returns its window sums; the seam folds them
+    sums = rlc_finish.verdict(rlc_verdict)
+    verdict = lambda *a: sums                                   # noqa: E731
     monkeypatch.setattr(B, "_compiled_rlc_sharded", factory(
         "rlc_sharded", verdict))
     monkeypatch.setattr(B, "_compiled_rlc", factory("rlc", verdict))

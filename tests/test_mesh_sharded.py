@@ -16,6 +16,7 @@ import pytest
 from cometbft_tpu.crypto import aotbundle
 from cometbft_tpu.crypto import batch as B
 from cometbft_tpu.crypto import plan as P
+from cometbft_tpu.crypto import rlc_finish
 from cometbft_tpu.parallel import mesh as M
 
 pytestmark = pytest.mark.timeout(120)
@@ -119,12 +120,12 @@ def test_one_sharded_dispatch_per_bucket(monkeypatch):
 
     bb = 1024                     # chunk_bucket(300, 4 devices)
     monkeypatch.setattr(B, "_compiled_rlc_sharded",
-                        factory("rlc_sharded", np.asarray(True)))
+                        factory("rlc_sharded", rlc_finish.verdict(True)))
     monkeypatch.setattr(B, "_compiled_verify_sharded",
                         factory("verify_sharded", np.ones((bb,), bool)))
     monkeypatch.setattr(
         B, "_compiled_rlc",
-        factory("rlc_single", np.asarray(True)))
+        factory("rlc_single", rlc_finish.verdict(True)))
     monkeypatch.setattr(
         B, "_compiled_verify",
         factory("verify_single", np.ones((bb,), bool)))
@@ -139,14 +140,14 @@ def test_one_sharded_dispatch_per_bucket(monkeypatch):
     # an RLC reject localizes with exactly ONE sharded per-lane dispatch
     calls.clear()
     monkeypatch.setattr(B, "_compiled_rlc_sharded",
-                        factory("rlc_sharded", np.asarray(False)))
+                        factory("rlc_sharded", rlc_finish.verdict(False)))
     B.device_verify_ed25519(z, z, z, msgs, lens)
     assert calls == ["rlc_sharded", "verify_sharded"]
 
 
 def test_mesh_metrics_record_sharded_route(monkeypatch):
     monkeypatch.setattr(B, "_compiled_rlc_sharded",
-                        lambda devs: lambda *a: np.asarray(True))
+                        lambda devs: lambda *a: rlc_finish.verdict(True))
     gauge, occ, total = B._mesh_metrics()
     before = total.value(route="sharded")
     P.configure(mesh_shape=(4,))

@@ -13,6 +13,8 @@ import pytest
 pytestmark = [pytest.mark.timeout(900), pytest.mark.slow]
 
 from cometbft_tpu.crypto import _ed25519_py as ref
+from cometbft_tpu.crypto import _native_ed25519 as native
+from cometbft_tpu.crypto import rlc_finish
 from cometbft_tpu.ops import ed25519, rlc, scalar, fe
 from cometbft_tpu.testing import dense_signature_batch
 
@@ -22,6 +24,11 @@ L = scalar.L_INT
 def _z(n, seed=3):
     rng = np.random.default_rng(seed)
     return rlc.host_rlc_coeffs(n, rng_bytes=rng.bytes(16 * n))
+
+
+def _ok(sums) -> bool:
+    """The verdict of an RLC program's output: the host folds its sums."""
+    return rlc_finish.finish(np.asarray(sums))[0]
 
 
 def test_mul_mod_l_and_sum_mod_l():
@@ -44,7 +51,41 @@ def test_mul_mod_l_and_sum_mod_l():
 def test_rlc_accepts_valid_batch():
     args, _ = dense_signature_batch(24, msg_len=80, seed=42)
     ok = jax.jit(rlc.verify_batch_rlc)(*args, _z(24))
-    assert bool(np.asarray(ok))
+    assert _ok(ok)
+
+
+def test_rlc_sums_fold_the_same_natively_and_in_python():
+    """The kernel's real output at the 16-lane bucket: loose limbs (the
+    chip does not freeze), one transfer, and both folds agree on it."""
+    args, _ = dense_signature_batch(16, msg_len=80, seed=47)
+    pub, rb, sb, blocks, active = [np.asarray(a).copy() for a in args]
+    fn = jax.jit(rlc.verify_batch_rlc)
+    good = np.asarray(fn(pub, rb, sb, blocks, active, _z(16)))
+    sb[3, 0] ^= 1
+    bad = np.asarray(fn(pub, rb, sb, blocks, active, _z(16)))
+    assert good.shape == rlc_finish.SHAPE and good.dtype == np.int32
+    assert 0 <= good.min() and fe.MASK < good.max() <= fe.LIMB_MAX
+    assert good[0, rlc_finish.OK] == 1 and bad[0, rlc_finish.OK] == 1
+    assert rlc_finish.fold_python(good) and not rlc_finish.fold_python(bad)
+    if native.available():
+        assert native.rlc_fold(good) is True and native.rlc_fold(bad) is False
+
+
+def test_rlc_sharded_returns_the_sums_of_the_whole_batch():
+    """Two emulated devices, eight lanes each: the partial window sums
+    combine into one replicated packed array that folds like the
+    single-device program's (the limbs may differ: the fold reads them
+    mod p)."""
+    from cometbft_tpu.parallel import mesh as M
+
+    args, _ = dense_signature_batch(16, msg_len=80, seed=48)
+    pub, rb, sb, blocks, active = [np.asarray(a).copy() for a in args]
+    fn = M.sharded_kernel("rlc", jax.devices()[:2])
+    good = np.asarray(fn(pub, rb, sb, blocks, active, _z(16)))
+    assert good.shape == rlc_finish.SHAPE and good.dtype == np.int32
+    assert _ok(good) and rlc_finish.fold_python(good)
+    rb[11, 3] ^= 4                              # a lane of the second shard
+    assert not _ok(fn(pub, rb, sb, blocks, active, _z(16)))
 
 
 def test_rlc_rejects_each_tamper_surface():
@@ -62,8 +103,8 @@ def test_rlc_rejects_each_tamper_surface():
             p2[11, 5] ^= 2
         else:
             b2[13, 0, 0] ^= 1
-        assert not bool(np.asarray(fn(p2, r2, s2, b2, active, z))), tamper
-    assert bool(np.asarray(fn(pub, rb, sb, blocks, active, z)))
+        assert not _ok(fn(p2, r2, s2, b2, active, z)), tamper
+    assert _ok(fn(pub, rb, sb, blocks, active, z))
 
 
 def test_rlc_padding_lanes_do_not_contribute():
@@ -78,10 +119,10 @@ def test_rlc_padding_lanes_do_not_contribute():
     assert (z[12:] == 0).all() and (z[:12] != 0).any(axis=1).all()
     sb[13, 0] ^= 1                         # tamper INSIDE the padding
     ok = jax.jit(rlc.verify_batch_rlc)(pub, rb, sb, blocks, active, z)
-    assert bool(np.asarray(ok))
+    assert _ok(ok)
     sb[5, 0] ^= 1                          # tamper an ACTIVE lane
     ok2 = jax.jit(rlc.verify_batch_rlc)(pub, rb, sb, blocks, active, z)
-    assert not bool(np.asarray(ok2))
+    assert not _ok(ok2)
 
 
 def test_rlc_invalid_padding_lane_cannot_veto():
@@ -100,12 +141,12 @@ def test_rlc_invalid_padding_lane_cannot_veto():
     rb[13] = 0xFF                          # not a curve point: ok_r False
     sb[14] = 0xFF                          # s >= L: ok_s False
     ok = jax.jit(rlc.verify_batch_rlc)(pub, rb, sb, blocks, active, z)
-    assert bool(np.asarray(ok)), \
+    assert _ok(ok), \
         "garbage padding lane vetoed a fully-valid batch"
     # the same garbage on an ACTIVE lane must still reject
     pub[3] = 0xFF
     ok2 = jax.jit(rlc.verify_batch_rlc)(pub, rb, sb, blocks, active, z)
-    assert not bool(np.asarray(ok2))
+    assert not _ok(ok2)
 
 
 def test_rlc_gather_variant_matches():
@@ -121,11 +162,11 @@ def test_rlc_gather_variant_matches():
     fn = jax.jit(rlc.verify_batch_rlc_gather)
     z = _z(b)
     ok = fn(tab, ok_a, scope.astype(np.int32), rb, sb, blocks, active, z)
-    assert bool(np.asarray(ok))
+    assert _ok(ok)
     sb2 = np.asarray(sb).copy()
     sb2[4, 2] ^= 8
     ok2 = fn(tab, ok_a, scope.astype(np.int32), rb, sb2, blocks, active, z)
-    assert not bool(np.asarray(ok2))
+    assert not _ok(ok2)
 
 
 def test_rlc_accepts_zip215_torsion_edge_cases():
@@ -188,4 +229,4 @@ def test_rlc_accepts_zip215_torsion_edge_cases():
     ok = jax.jit(rlc.verify_batch_rlc)(
         arr(pubs), arr([s[:32] for s in sigs]),
         arr([s[32:] for s in sigs]), blocks, active, _z(b))
-    assert bool(np.asarray(ok))
+    assert _ok(ok)

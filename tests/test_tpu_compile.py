@@ -87,7 +87,7 @@ def test_one_chip_bucket_compiles_for_v5e(topo, bucket):
 
 @pytest.mark.timeout(COMPILE_TIMEOUT_S)
 def test_sharded_rlc_compiles_for_four_v5e_chips(topo):
-    """The lane-sharded RLC verdict over a 4-chip mesh: the one main-path
+    """The lane-sharded RLC sums over a 4-chip mesh: the one main-path
     program whose partition needs a collective (the per-device partial
     window sums cross the interconnect)."""
     compiled = compile_bucket(topo, CompileBucket("rlc", 4096, 2),
@@ -96,3 +96,13 @@ def test_sharded_rlc_compiles_for_four_v5e_chips(topo):
     hlo = compiled.as_text()
     assert "all-gather" in hlo or "all-reduce" in hlo \
         or "collective-permute" in hlo or "all-to-all" in hlo
+    # the program ends at the window sums: one replicated int32 array
+    # for the host to fold (crypto/rlc_finish.py), no one-lane ladder
+    from cometbft_tpu.crypto import rlc_finish
+    from cometbft_tpu.ops import rlc
+
+    (out,) = jax.tree_util.tree_leaves(compiled.output_shardings)
+    assert out.is_fully_replicated
+    assert "s32[20,386]" in hlo and rlc_finish.SHAPE == (20, 386)
+    assert "rlc_ladder" not in hlo and "rlc_sums" in hlo
+    assert not hasattr(rlc, "_rlc_ladder")

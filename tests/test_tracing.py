@@ -291,6 +291,7 @@ def test_verify_dense_spans_at_the_16_lane_bucket(monkeypatch):
 
     from cometbft_tpu.crypto import batch as B
     from cometbft_tpu.crypto import plan as P
+    from cometbft_tpu.crypto import rlc_finish
     from cometbft_tpu.testing import make_light_chain
     from cometbft_tpu.types import validation as V
 
@@ -340,7 +341,7 @@ def test_verify_dense_spans_at_the_16_lane_bucket(monkeypatch):
     # entry's span closes not ok when the commit is refused
     tracing.clear()
     monkeypatch.setattr(B, "_compiled_rlc_gather",
-                        lambda: lambda *a: np.bool_(False))
+                        lambda: lambda *a: rlc_finish.verdict(False))
     P.configure(rlc_min_lanes=1)
     bad = make_light_chain(1, n_vals=4)[0].commit
     bad.signatures[1].signature = bytes(64)
@@ -353,6 +354,10 @@ def test_verify_dense_spans_at_the_16_lane_bucket(monkeypatch):
         ("rlc_gather", 4, 16), ("gather", 4, 16)]
     assert [r["attrs"]["ok"] for r in recs if r["name"] == "readback"] \
         == [False, False]
+    (fold,) = [r for r in recs if r["name"] == "finish"]     # the RLC sums
+    assert fold["attrs"]["ok"] is False and fold["parent"] == next(
+        r["id"] for r in recs if r["name"] == "readback"
+        and r["attrs"]["kind"] == "rlc_gather")
     (entry,) = [r for r in recs if r["name"] == "verify"]
     assert entry["attrs"]["entry"] == "VerifyCommit" \
         and entry["attrs"]["ok"] is False
