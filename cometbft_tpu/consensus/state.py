@@ -105,6 +105,9 @@ class ConsensusState:
         self.fatal_error: Exception | None = None
         self._stopped = asyncio.Event()
         self.decided = asyncio.Event()      # pulses on every commit (tests)
+        # (height, verdict) of the stored extended commit last verified
+        # (_stored_commit_verifies)
+        self._stored_commit_verdict: tuple[int, bool] = (-1, False)
 
         # outbound hooks (set by the in-proc harness or the p2p reactor)
         self.broadcast_proposal: Callable[[Proposal], None] = lambda p: None
@@ -722,7 +725,7 @@ class ConsensusState:
             return rs.last_commit.make_extended_commit()
         stored = self.block_store.load_block_extended_commit(rs.height - 1)
         if stored is not None:
-            return stored
+            return stored if self._stored_commit_verifies(stored) else None
         seen = self.block_store.load_seen_commit()
         if seen is not None and seen.height == rs.height - 1:
             if self.state.consensus_params.feature.vote_extensions_enabled(
@@ -743,6 +746,66 @@ class ConsensusState:
                                    for cs in seen.signatures],
                                   seen.agg_signature, seen.agg_signers)
         return None
+
+    def _stored_commit_verifies(self, stored: ExtendedCommit) -> bool:
+        """A STORED extended commit for the previous height (after
+        blocksync, statesync or a restart: the node holds no precommit set
+        of its own) is verified once before it is proposed from, and the
+        verdict kept for that height: this runs at every proposal attempt.
+        With vote extensions on at that height the whole commit goes
+        through ``VerifyExtendedCommit`` (every vote and every extension
+        signature, one batch; ``patient``: a 10,000-validator commit is
+        five chunks of device work, and this caller would rather queue
+        than fail over to the host), else the stripped commit through
+        ``VerifyCommit``.  The node's own precommit set was verified vote
+        by vote and never comes here.
+
+        Upstream panics in ``reconstructLastCommit`` when the stored commit
+        does not verify.  This node declines to propose instead (the
+        caller returns None, the round times out, another proposer
+        goes): blocksync stores what a PEER sent after
+        ``ensure_extensions`` alone, so a panic here would let one peer
+        stop a validator at every start, while a node that does not
+        propose still votes, commits and serves.  The call blocks the
+        consensus task for as long as one verification takes (no handler
+        in this file hands verification to a thread: the state machine is
+        single-writer), once per start."""
+        height = stored.height
+        if self._stored_commit_verdict[0] != height:
+            from ..types import validation
+
+            vals, ok = self.rs.last_validators, False
+            backend = getattr(self.block_exec, "backend", None)   # the node's
+            ext_on = self.state.consensus_params.feature \
+                .vote_extensions_enabled(height)
+            try:
+                if vals is None:
+                    raise validation.ErrInvalidCommit(
+                        "no validators known for the stored commit's height")
+                if ext_on:
+                    validation.VerifyExtendedCommit(
+                        self.state.chain_id, vals, self.state.last_block_id,
+                        height, stored, backend=backend, patient=True)
+                elif not stored.ensure_extensions(False):
+                    raise validation.ErrInvalidCommit(
+                        "extensions in a commit of a height without them")
+                else:
+                    validation.VerifyCommit(
+                        self.state.chain_id, vals, self.state.last_block_id,
+                        height, stored.to_commit(), backend=backend)
+                ok = True
+            except validation.CommitVerificationError as e:
+                kind = None
+                if isinstance(e, validation.ErrInvalidSignature):
+                    kind = "extension" if isinstance(
+                        e, validation.ErrInvalidExtensionSignature) else "vote"
+                self.log.error(
+                    "stored extended commit does not verify: not proposing "
+                    "from it", height=height,
+                    validator_index=getattr(e, "idx", None), signature=kind,
+                    err=repr(e))
+            self._stored_commit_verdict = (height, ok)
+        return self._stored_commit_verdict[1]
 
     # ------------------------------------------------------------ proposal rx
 

@@ -26,6 +26,26 @@ BLOCK_ID_FLAG_AGGREGATE = 4
 
 # max individual signature size: 64 ed25519, 96 bls12_381 G2
 MAX_SIGNATURE_SIZE = 96
+# widest vote extension ExtendedCommit.dense_columns() lays out as a
+# matrix (every lane is padded to the widest); wider ones verify through
+# the per-lane loop
+MAX_DENSE_EXTENSION_BYTES = 1024
+
+
+def _byte_rows(items: list, lens, width: int):
+    """``items`` (bytes each, ``lens`` their lengths) as an ``(n, width)``
+    uint8 matrix: shorter ones zero-padded, a wider one left zero."""
+    import numpy as np
+
+    n = len(items)
+    if not n or not width:
+        return np.zeros((n, width), np.uint8)
+    if bool((lens == width).all()):
+        blob = b"".join(items)
+    else:
+        blob = b"".join(x.ljust(width, b"\0") if len(x) <= width
+                        else bytes(width) for x in items)
+    return np.frombuffer(blob, np.uint8).reshape(n, width)
 
 
 def signer_bitmap(indices, n: int) -> bytes:
@@ -452,3 +472,71 @@ class ExtendedCommit:
         v.extension = e.extension
         v.extension_signature = e.extension_signature
         return v
+
+    def stripped(self) -> Commit:
+        """:meth:`to_commit`, made once and kept on the object (an
+        extended commit is immutable once decoded): the vote half's
+        columns and sign-bytes templates memoise on it."""
+        c = self.__dict__.get("_stripped")
+        if c is None:
+            c = self.__dict__["_stripped"] = self.to_commit()
+        return c
+
+    def __deepcopy__(self, memo):
+        # as Commit.__deepcopy__: derived caches must not survive a copy
+        import copy as _copy
+
+        return ExtendedCommit(self.height, self.round,
+                              _copy.deepcopy(self.block_id, memo),
+                              _copy.deepcopy(self.extended_signatures, memo),
+                              self.agg_signature, self.agg_signers)
+
+    def extension_sign_bytes(self, chain_id: str, idx: int) -> bytes:
+        """CanonicalVoteExtension bytes under extension signature ``idx``:
+        byte for byte ``to_extended_vote(idx).extension_sign_bytes``."""
+        return canonical.canonical_vote_extension_sign_bytes(
+            chain_id, self.height, self.round,
+            self.extended_signatures[idx].extension)
+
+    def extension_sign_bytes_suffix(self, chain_id: str) -> bytes:
+        """What follows the extension field in every lane's
+        CanonicalVoteExtension body (height, round, chain id): the body
+        of an empty extension, taken from the canonical encoder itself
+        so that the dense rows cannot drift from it."""
+        whole = canonical.canonical_vote_extension_sign_bytes(
+            chain_id, self.height, self.round, b"")
+        k = 1                # strip the varint length prefix
+        while len(wire.varint(len(whole) - k)) != k:
+            k += 1
+        return whole[k:]
+
+    def dense_columns(self):
+        """Columnar view for ``VerifyExtendedCommit``'s dense path, cached
+        on the object as :meth:`Commit.dense_columns` is: ``(flags, ts,
+        sigs, ext_lens int64 (N,), exts uint8 (N,W), ext_sig_lens int64
+        (N,), ext_sigs uint8 (N,64))``.  The first three are the stripped
+        commit's own columns; ``exts`` holds every lane's extension
+        zero-padded to the widest, ``ext_sigs`` the 64-byte extension
+        signatures (rows of another length stay zero: ``ext_sig_lens``
+        tells).  None when the vote columns do not apply or an extension
+        is too wide for a dense matrix: callers use the per-lane loop."""
+        cols = self.__dict__.get("_dense_cols", False)
+        if cols is not False:
+            return cols
+        import numpy as np
+
+        cols = None
+        base = self.stripped().dense_columns()
+        es = self.extended_signatures
+        n = len(es)
+        exts = [e.extension for e in es]
+        width = max(map(len, exts), default=0)
+        if base is not None and width <= MAX_DENSE_EXTENSION_BYTES:
+            xsigs = [e.extension_signature for e in es]
+            ext_lens = np.fromiter(map(len, exts), np.int64, n)
+            ext_sig_lens = np.fromiter(map(len, xsigs), np.int64, n)
+            cols = (*base, ext_lens, _byte_rows(exts, ext_lens, width),
+                    ext_sig_lens, _byte_rows(xsigs, ext_sig_lens, 64))
+        self.__dict__["_dense_cols"] = cols
+        return cols
+

@@ -817,3 +817,274 @@ def VerifyCommitLightTrustingAllSignatures(chain_id: str, vals: ValidatorSet,
     VerifyCommitLightTrusting(chain_id, vals, commit, trust_level,
                               backend=backend, count_all=True,
                               use_cache=False)
+
+
+# ------------------------------------------------- extended commits (ABCI 2.0)
+
+
+class ErrInvalidExtensionSignature(ErrInvalidSignature):
+    """Validator ``idx``'s vote signature holds and its vote-extension
+    signature does not (``types/vote.go`` VerifyExtension)."""
+
+    def __init__(self, idx: int, msg: str = ""):
+        super().__init__(idx, msg or f"wrong extension signature (#{idx})")
+
+
+_EXTENDED_METRICS = None
+
+
+def _extended_metrics():
+    """``types_extended_commit_*``, registered on first use."""
+    global _EXTENDED_METRICS
+    if _EXTENDED_METRICS is None:
+        from ..libs import metrics as m
+
+        _EXTENDED_METRICS = (
+            m.counter("types_extended_commit_lanes_total",
+                      "signatures VerifyExtendedCommit submitted, by kind "
+                      "(vote / extension)"),
+            m.counter("types_extended_commit_verify_total",
+                      "VerifyExtendedCommit calls by result (ok / "
+                      "bad_vote_sig / bad_ext_sig / refused)"))
+    return _EXTENDED_METRICS
+
+
+def VerifyExtendedCommit(chain_id: str, vals: ValidatorSet, block_id,
+                         height: int, ext_commit, *,
+                         backend: str | None = None,
+                         patient: bool = False) -> None:
+    """An ``ExtendedCommit`` verified whole, as one batch: upstream's
+    ``ExtendedCommit.ToExtendedVoteSet`` (``types/block.go``), which adds
+    every vote through ``VerifyVoteAndExtension`` (``types/vote.go``),
+    with :func:`VerifyCommit`'s basics and tally.
+
+    Size, height and block id must match; extensions must be where
+    vote extensions put them (an extension signature on every for-block
+    lane, nothing on a nil or absent one: ``ErrInvalidCommit``
+    otherwise); EVERY non-absent lane's vote signature and every
+    for-block lane's extension signature is verified, with no early exit
+    at +2/3; the for-block power must exceed two thirds
+    (``ErrNotEnoughVotingPower``).  A bad signature raises
+    ``ErrInvalidSignature(idx)`` (the vote's) or its subclass
+    :class:`ErrInvalidExtensionSignature` (the extension's), ``idx`` the
+    FIRST validator in index order with a bad signature, its vote
+    before its extension: what upstream's in-order ``AddVote`` reports.
+
+    Both kinds of rows go to ONE ``verify_dense`` call over the
+    validator table (every index twice: vote lanes, then extension lanes,
+    each in validator order).  ``patient`` queues the dispatch behind one
+    in flight instead of failing over to the host: the callers are a node
+    starting or leaving blocksync, not the consensus loop
+    (``crypto/batch._device_call``).  Mixed key types, aggregate lanes
+    and odd signature sizes take the per-lane ``BatchVerifier``, which
+    sends its Ed25519 lanes to the device and the rest to the host."""
+    results = _extended_metrics()[1]
+    with tracing.span("types.validation", "verify", entry="extended",
+                      height=height, commits=1, lanes=ext_commit.size(),
+                      ext_lanes=0, ok=True) as sp:
+        try:
+            _check_commit_basics(vals, ext_commit, height, block_id)
+            needed = vals.total_voting_power() * 2 // 3
+            if not _dense_verify_extended(
+                    chain_id, vals, ext_commit, needed,
+                    backend or _DEFAULT_BACKEND, patient, sp):
+                _verify_extended_loop(chain_id, vals, ext_commit, needed,
+                                      backend or _DEFAULT_BACKEND, sp)
+        except ErrInvalidExtensionSignature:
+            results.inc(result="bad_ext_sig")
+            raise
+        except ErrInvalidSignature:
+            results.inc(result="bad_vote_sig")
+            raise
+        except CommitVerificationError:
+            results.inc(result="refused")
+            raise
+        results.inc(result="ok")
+
+
+_EXTENSIONS_MISPLACED = ("invalid commit: vote extensions are not where "
+                         "extensions-enabled puts them (a signature on "
+                         "every for-block lane, nothing on another)")
+
+
+def _first_bad_extended(vote_scope, ext_scope, oks) -> Exception:
+    """The error of the first validator in index order with a refuted
+    lane, its vote lane before its extension lane; ``oks`` holds the
+    vote lanes' verdicts, then the extension lanes'."""
+    nv = len(vote_scope)
+    idx, is_ext = min((int(vote_scope[j]), False) if j < nv
+                      else (int(ext_scope[j - nv]), True)
+                      for j, ok in enumerate(oks) if not ok)
+    return (ErrInvalidExtensionSignature if is_ext
+            else ErrInvalidSignature)(idx)
+
+
+def _dense_verify_extended(chain_id: str, vals: ValidatorSet, ec,
+                           needed: int, backend: str, patient: bool,
+                           entry_span) -> bool:
+    """Vectorized core of :func:`VerifyExtendedCommit`: the vote rows by
+    the native builder (``rows`` span), the extension rows in numpy
+    (``ext_rows`` span), then ONE dense verification over both.  Returns
+    True when it handled the commit (raising as the entry documents),
+    False when not applicable (a key that is not Ed25519, an aggregate,
+    a signature that is not 64 bytes, no native library): the caller
+    loops.  ``entry_span`` (the entry's open span, or None) learns the
+    number of extension lanes."""
+    import numpy as np
+
+    from ..crypto import _native_ed25519 as nat
+    from .commit import (BLOCK_ID_FLAG_ABSENT, BLOCK_ID_FLAG_AGGREGATE,
+                         BLOCK_ID_FLAG_COMMIT)
+
+    dense = vals.dense()
+    if dense is None or ec.agg_signature or ec.agg_signers \
+            or not nat.available():
+        return False
+    pubs, powers = dense
+    with tracing.span("types.validation", "rows", commits=1) as sp:
+        commit = ec.stripped()
+        cols = commit.dense_columns()
+        if cols is None:
+            return False
+        flags, ts, sigmat = cols
+        if len(flags) != len(powers) or \
+                (flags == BLOCK_ID_FLAG_AGGREGATE).any():
+            return False
+        vote_scope = np.nonzero(flags != BLOCK_ID_FLAG_ABSENT)[0]
+        pre_c, pre_n, post = commit.sign_bytes_templates(chain_id)
+        vote_rows = nat.build_vote_sign_bytes(
+            pre_c, pre_n, post, ts[vote_scope], flags[vote_scope])
+        if vote_rows is None:
+            return False
+        if sp is not None:
+            sp.attrs["lanes"] = int(vote_scope.size)
+    with tracing.span("types.validation", "ext_rows", lanes=0) as sp:
+        cols = ec.dense_columns()
+        if cols is None:
+            return False
+        ext_lens, extmat, ext_sig_lens, ext_sigmat = cols[3:]
+        commit_mask = flags == BLOCK_ID_FLAG_COMMIT
+        ext_scope = np.nonzero(commit_mask)[0]
+        if (ext_sig_lens[ext_scope] == 0).any() or \
+                (ext_lens + ext_sig_lens)[~commit_mask].any():
+            raise ErrInvalidCommit(_EXTENSIONS_MISPLACED)
+        if (ext_sig_lens[ext_scope] != 64).any():
+            return False
+        tally = int(powers[ext_scope].sum())
+        msgs, lens = _dense_extended_rows(
+            vote_rows, _dense_build_extension_rows(
+                ec.extension_sign_bytes_suffix(chain_id),
+                extmat[ext_scope], ext_lens[ext_scope]))
+        scope = np.concatenate([vote_scope, ext_scope])
+        sigs = np.concatenate([sigmat[vote_scope], ext_sigmat[ext_scope]])
+        pubs_sel = np.ascontiguousarray(pubs[scope])
+        if sp is not None:
+            sp.attrs["lanes"] = int(ext_scope.size)
+        if entry_span is not None:
+            entry_span.attrs["ext_lanes"] = int(ext_scope.size)
+    if scope.size:
+        res = cryptobatch.verify_dense(
+            backend, pubs_sel, sigs, msgs, lens, valset_pubs=pubs,
+            scope=scope, patient=patient)
+        if res is None:
+            return False
+        lanes = _extended_metrics()[0]
+        lanes.inc(int(vote_scope.size), kind="vote")
+        lanes.inc(int(ext_scope.size), kind="extension")
+        if not res[0]:
+            raise _first_bad_extended(vote_scope, ext_scope, res[1])
+    if tally <= needed:
+        raise ErrNotEnoughVotingPower(
+            f"tallied {tally} <= needed {needed}")
+    return True
+
+
+def _dense_build_extension_rows(suffix: bytes, extmat, ext_lens):
+    """CanonicalVoteExtension sign-bytes rows (``canonical.
+    canonical_vote_extension_sign_bytes``) for the given lanes'
+    extensions: ``(msgs (k, stride) uint8 zero-padded, lens (k,))``.  A
+    lane is its body's length, field 1 (omitted when empty, as proto3
+    does), then ``suffix`` (height, round, chain id), so lanes of one
+    extension length share everything but the extension itself and are
+    written as one block."""
+    import numpy as np
+
+    from . import wire
+
+    k = len(ext_lens)
+    widest = int(ext_lens.max()) if k else 0
+    stride = 5 + 6 + widest + len(suffix)
+    msgs = np.zeros((k, stride), np.uint8)
+    lens = np.zeros((k,), np.int64)
+    tail = np.frombuffer(suffix, np.uint8)
+    for n in np.unique(ext_lens):
+        n = int(n)
+        rows = np.nonzero(ext_lens == n)[0]
+        field = wire.tag(1, wire.WIRE_BYTES) + wire.varint(n) if n else b""
+        head = wire.varint(len(field) + n + len(suffix)) + field
+        at = len(head)
+        msgs[rows, :at] = np.frombuffer(head, np.uint8)
+        msgs[rows, at:at + n] = extmat[rows, :n]
+        msgs[rows, at + n:at + n + len(tail)] = tail
+        lens[rows] = at + n + len(tail)
+    return msgs, lens
+
+
+def _dense_extended_rows(vote_rows, ext_rows):
+    """Vote rows, then extension rows, as one zero-padded matrix."""
+    import numpy as np
+
+    (vm, vl), (em, el) = vote_rows, ext_rows
+    stride = max(vm.shape[1], em.shape[1])
+    msgs = np.zeros((len(vl) + len(el), stride), np.uint8)
+    msgs[:len(vl), :vm.shape[1]] = vm
+    msgs[len(vl):, :em.shape[1]] = em
+    return msgs, np.concatenate([vl, el])
+
+
+def _verify_extended_loop(chain_id: str, vals: ValidatorSet, ec,
+                          needed: int, backend: str, entry_span) -> None:
+    """Per-lane core of :func:`VerifyExtendedCommit` for what the dense
+    rows do not cover (a key that is not Ed25519, a BLS aggregate, an odd
+    signature size, no native builder): the same lanes in the same
+    order through a ``BatchVerifier``, which resolves each lane's key
+    type as the other entries' loops do."""
+    commit = ec.stripped()
+    # raises on any aggregate problem, so every AGGREGATE lane's vote is
+    # proven by the aggregate; its extension signature is its own lane
+    _verify_aggregate(chain_id, vals, commit, lookup_by_address=False)
+    bv = cryptobatch.create_batch_verifier(backend)
+    vote_scope, ext_scope, ext_items = [], [], []
+    tally = 0
+    for idx, e in enumerate(ec.extended_signatures):
+        cs = e.commit_sig
+        if (e.extension or e.extension_signature) if not cs.is_commit() \
+                else not e.extension_signature:
+            raise ErrInvalidCommit(_EXTENSIONS_MISPLACED)
+        if cs.is_absent():
+            continue
+        val = vals.get_by_index(idx)
+        if not cs.is_aggregate():
+            bv.add(val.pub_key, commit.vote_sign_bytes_for(
+                chain_id, idx, val.pub_key.type()), cs.signature)
+            vote_scope.append(idx)
+        if cs.is_commit():
+            ext_items.append((val.pub_key,
+                              ec.extension_sign_bytes(chain_id, idx),
+                              e.extension_signature))
+            ext_scope.append(idx)
+            tally += val.voting_power
+    for item in ext_items:
+        bv.add(*item)
+    if entry_span is not None:
+        entry_span.attrs["ext_lanes"] = len(ext_scope)
+    lanes = _extended_metrics()[0]
+    lanes.inc(len(vote_scope), kind="vote")
+    lanes.inc(len(ext_scope), kind="extension")
+    if len(bv) > 0:
+        ok, oks = bv.verify()
+        if not ok:
+            raise _first_bad_extended(vote_scope, ext_scope, oks)
+    if tally <= needed:
+        raise ErrNotEnoughVotingPower(
+            f"tallied {tally} <= needed {needed}")
