@@ -33,6 +33,7 @@ import functools
 import threading
 import time
 from abc import ABC, abstractmethod
+from typing import NamedTuple
 
 import numpy as np
 
@@ -362,68 +363,103 @@ def _build_valset_tables(key, pubs_full, devices: tuple) -> tuple:
 
 
 def device_verify_ed25519_cached(valset_pubs, scope, pubs_rows, rs, ss,
-                                 msgs, msg_lens, device=None) -> np.ndarray:
+                                 msgs, msg_lens, device=None,
+                                 chunks=None) -> np.ndarray:
     """Dense verify through the per-valset table cache: like
     :func:`device_verify_ed25519` but A decompression + table building
     happen once per validator set, not once per batch.  ``scope`` (B,)
     are validator indices into ``valset_pubs``; ``pubs_rows`` (B,32) are
-    the gathered pubkey bytes (still needed for the R||A||M hash)."""
+    the gathered pubkey bytes (still needed for the R||A||M hash).
+
+    The chunk loop is software-pipelined: every chunk is packed and its
+    first program launched before any is read back (launches are
+    asynchronous and the device runs them in order, so it goes from one
+    chunk to the next without waiting for the host); the readbacks and
+    RLC folds follow in chunk order, and a refuted chunk's ``gather`` is
+    launched when its fold says so.  ``chunks``: the call's chunks packed
+    already, by a caller that was going to queue
+    (:func:`_packed_chunks`); else they are packed here, chunk k+1 while
+    chunk k runs."""
     b = pubs_rows.shape[0]
     if b == 0:
         return np.zeros((0,), bool)
     devices = _resolve_devices(device)
     tab, ok, n_pad = _valset_tables(valset_pubs, devices)
     place = _single_device_place(device, devices)
+    if chunks is None:
+        chunks = _packed_chunks(scope, pubs_rows, rs, ss, msgs, msg_lens,
+                                devices)
+    flying = []
+    for start, end, bb, lane_args, z10 in chunks:
+        # steady-state fast path: one RLC verdict over the cached tables
+        # (lane-sharded over a multi-chip mesh); below the RLC threshold
+        # the per-lane program at once
+        args = lane_args if z10 is None else lane_args + (z10,)
+        flying.append((start, end, lane_args, _launch_cached(
+            z10 is not None, tab, ok, n_pad, args, end - start, bb,
+            devices, place)))
+        _note_mesh(devices, end - start, bb)
     results = np.zeros((b,), bool)
+    for start, end, lane_args, flight in flying:
+        c = end - start
+        out = _readback(flight)
+        if flight.kind.startswith("rlc"):
+            if out:
+                _metrics()[1].inc(c, route="device_rlc" if len(devices) <= 1
+                                  else "device_rlc_sharded")
+                results[start:end] = True
+                continue
+            # a reject falls through to per-lane localization
+            out = _readback(_launch_cached(False, tab, ok, n_pad, lane_args,
+                                           c, flight.bucket, devices, place))
+        results[start:end] = out[:c]
+    return results
+
+
+def _packed_chunks(scope, pubs_rows, rs, ss, msgs, msg_lens, devices: tuple,
+                   queued: bool = False):
+    """The chunks of a cached-route call, each packed as the consumer
+    asks for it: ``(start, end, bucket, lane_args, z10)``.
+    :func:`device_verify_ed25519_cached`'s launch loop takes one at a
+    time, so chunk k+1 is packed while chunk k runs; a ``queued`` caller
+    drains the generator on its own thread, under its wait for the
+    dispatch ahead of it (host work only: the table lookup and everything
+    that touches the device stay on the device-owner thread)."""
+    b = pubs_rows.shape[0]
     # a mesh multiplies the chunk cap: one sharded dispatch carries a
     # cap-sized lane slab per device
     cap = _plan.active().lane_buckets[-1] * max(1, len(devices))
     for start in range(0, b, cap):
         end = min(start + cap, b)
-        c = end - start
         sl = slice(start, end)
-        bb = _chunk_bucket(c, devices)
-        lane_args, z10 = _pack(pubs_rows[sl], rs[sl], ss[sl], msgs[sl],
-                               msg_lens[sl], bb, scope=scope[sl])
-        nb_blocks = lane_args[3].shape[1]
-        _note_mesh(devices, c, bb)
-        if z10 is not None:
-            # steady-state fast path: one RLC verdict over the cached
-            # tables (lane-sharded over a multi-chip mesh); a reject
-            # falls through to per-lane localization
-            rl_args = lane_args + (z10,)
-            if len(devices) > 1:
-                rfn = _aot_fn_mesh(f"rlc_gather:{n_pad}", bb, nb_blocks,
-                                   devices)
-                if rfn is None:
-                    rfn = _compiled_rlc_gather_sharded(devices)
-                rkind = "rlc_gather_sharded"
-            else:
-                rkind = "rlc_gather"
-                rfn = _aot_fn(f"rlc_gather:{n_pad}", bb, nb_blocks, place)
-                if rfn is None:
-                    rfn = _compiled_rlc_gather()
-                    if place is not None:
-                        rl_args = _put(rl_args, place)
-            if _run(rkind, rfn, (tab, ok, *rl_args), c, bb):
-                _metrics()[1].inc(c, route="device_rlc" if len(devices) <= 1
-                                  else "device_rlc_sharded")
-                results[start:end] = True
-                continue
-        if len(devices) > 1:
-            fn = _aot_fn_mesh(f"gather:{n_pad}", bb, nb_blocks, devices)
-            if fn is None:
-                fn = _compiled_verify_gather(devices)
-        else:
-            fn = _aot_fn(f"gather:{n_pad}", bb, nb_blocks, place)
-            if fn is None:
-                fn = _compiled_verify_gather(devices)
-                if place is not None:
-                    lane_args = _put(lane_args, place)
-        out = _run("gather_sharded" if len(devices) > 1 else "gather",
-                   fn, (tab, ok, *lane_args), c, bb)
-        results[start:end] = out[:c]
-    return results
+        bb = _chunk_bucket(end - start, devices)
+        yield (start, end, bb) + _pack(
+            pubs_rows[sl], rs[sl], ss[sl], msgs[sl], msg_lens[sl], bb,
+            scope=scope[sl], ahead=queued or start > 0)
+
+
+def _launch_cached(rlc: bool, tab, ok, n_pad: int, args: tuple, lanes: int,
+                   bb: int, devices: tuple, place) -> "_Flight":
+    """Launch one cached-route program over a packed chunk: the RLC
+    verdict or the per-lane verify, sharded over a mesh, from the AOT
+    bundle where it holds the shape."""
+    name = "rlc_gather" if rlc else "gather"
+    nb_blocks = args[3].shape[1]
+    if len(devices) > 1:
+        kind = name + "_sharded"
+        fn = _aot_fn_mesh(f"{name}:{n_pad}", bb, nb_blocks, devices)
+        if fn is None:
+            fn = _compiled_rlc_gather_sharded(devices) if rlc \
+                else _compiled_verify_gather(devices)
+    else:
+        kind = name
+        fn = _aot_fn(f"{name}:{n_pad}", bb, nb_blocks, place)
+        if fn is None:
+            fn = _compiled_rlc_gather() if rlc \
+                else _compiled_verify_gather(devices)
+            if place is not None:
+                args = _put(args, place)
+    return _launch(kind, fn, (tab, ok, *args), lanes, bb)
 
 
 # The device set and the bucket-selection math moved into the plan
@@ -560,13 +596,18 @@ def _padded_lane_args(pubs, rs, ss, msgs, msg_lens, bb):
     return pad(pubs), pad(rs), pad(ss), blocks, active
 
 
-def _pack(pubs, rs, ss, msgs, msg_lens, bb, scope=None):
+def _pack(pubs, rs, ss, msgs, msg_lens, bb, scope=None, ahead=False):
     """One chunk's kernel arguments, packed under a ``pack`` span on both
     device routes: the padded lane matrices (led by the chunk's table
     rows ``scope`` on the cached route, by the pubkeys otherwise) and,
-    from the RLC threshold up, the coefficient draw (else None)."""
+    from the RLC threshold up, the coefficient draw (else None).
+    ``ahead``: packed while the device had earlier work to cover it (on
+    a queued caller's thread, or with an earlier chunk of the call
+    launched), not with the device waiting for it."""
     b = pubs.shape[0]
-    with tracing.span("crypto.seam", "pack", lanes=b, bucket=bb) as sp:
+    _seam_chunks().inc(prepared="ahead" if ahead else "inline")
+    with tracing.span("crypto.seam", "pack", lanes=b, bucket=bb,
+                      ahead=ahead) as sp:
         lead, r32, s32, blocks, active = _padded_lane_args(
             pubs, rs, ss, msgs, msg_lens, bb)
         if scope is not None:
@@ -592,18 +633,35 @@ def _put(tree, place):
         return jax.device_put(tree, place)
 
 
-def _run(kind: str, fn, args: tuple, lanes: int, bb: int) -> np.ndarray:
-    """One compiled program: the ``launch`` span ends when the call
-    returns its (unready) result, the ``readback`` span when the verdict
-    is on the host.  The RLC kinds return their per-window sums, and the
-    verdict is the host's fold of them (``crypto/rlc_finish.py``), a
-    ``finish`` span inside ``readback``."""
+class _Flight(NamedTuple):
+    """A launched program whose result has not been read back."""
+    kind: str
+    out: object             # the program's (unready) result
+    bucket: int
+    launch_s: float         # host time inside the launch
+
+
+def _launch(kind: str, fn, args: tuple, lanes: int, bb: int) -> _Flight:
+    """Start one compiled program: the ``launch`` span ends when the call
+    returns its (unready) result."""
     t0 = time.perf_counter()
     with tracing.span("crypto.seam", "launch", kind=kind, lanes=lanes,
                       bucket=bb):
         out = fn(*args)
+    return _Flight(kind, out, bb, time.perf_counter() - t0)
+
+
+def _readback(flight: _Flight) -> np.ndarray:
+    """Wait for a launched program: the ``readback`` span ends when the
+    verdict is on the host.  The RLC kinds return their per-window sums,
+    and the verdict is the host's fold of them (``crypto/rlc_finish.py``),
+    a ``finish`` span inside ``readback``.  The dispatch is noted with
+    the host's time in its two spans, not with what the host did for
+    other chunks between them."""
+    kind = flight.kind
+    t0 = time.perf_counter()
     with tracing.span("crypto.seam", "readback", kind=kind) as sp:
-        out = np.asarray(out)
+        out = np.asarray(flight.out)
         if kind.startswith("rlc"):
             with tracing.span("crypto.seam", "finish") as fsp:
                 ok, native = rlc_finish.finish(out)
@@ -612,8 +670,14 @@ def _run(kind: str, fn, args: tuple, lanes: int, bb: int) -> np.ndarray:
             out = np.bool_(ok)
         if sp is not None:
             sp.attrs["ok"] = bool(out.all())
-    _note_dispatch(kind, bb, time.perf_counter() - t0)
+    _note_dispatch(kind, flight.bucket,
+                   flight.launch_s + time.perf_counter() - t0)
     return out
+
+
+def _run(kind: str, fn, args: tuple, lanes: int, bb: int) -> np.ndarray:
+    """One compiled program from launch to verdict."""
+    return _readback(_launch(kind, fn, args, lanes, bb))
 
 
 def _single_device_place(device, devices: tuple):
@@ -722,6 +786,18 @@ def _mesh_metrics():
         m.counter("crypto_mesh_dispatch_total",
                   "verify dispatches by route (sharded vs single)"),
     )
+
+
+@functools.cache
+def _seam_chunks():
+    """``crypto_seam_chunks_total{prepared}``: chunks packed ``ahead``
+    (under earlier device work) against ``inline`` (the device waiting)."""
+    from ..libs import metrics as m
+
+    return m.counter(
+        "crypto_seam_chunks_total",
+        "dispatch chunks packed, by whether earlier device work covered "
+        "the packing (ahead) or the device waited for it (inline)")
 
 
 def _note_mesh(devices: tuple, b: int, bb: int) -> None:
@@ -839,6 +915,13 @@ def patient_wait_s(lanes: int) -> float:
     return _DEVICE_WAIT_S * 2 + min(56.0, 2.0 * total / floor_sigs_per_s)
 
 
+def _device_busy() -> bool:
+    """Is a dispatch in flight on the device-owner thread (or queued
+    for it)?  The next submission waits behind it."""
+    fut = _DEVICE_INFLIGHT
+    return fut is not None and not fut.done()
+
+
 def _device_call(fn, patient: float = 0.0):
     """Run ``fn`` (a device dispatch) on the single device-owner thread,
     waiting at most ``_DEVICE_WAIT_S``.  Returns ``fn()``'s result, or
@@ -877,8 +960,7 @@ def _device_call(fn, patient: float = 0.0):
         if _DEVICE_POOL is None:
             _DEVICE_POOL = cf.ThreadPoolExecutor(
                 max_workers=1, thread_name_prefix="tpu-verify")
-    if _DEVICE_INFLIGHT is not None and not _DEVICE_INFLIGHT.done() \
-            and not patient:
+    if not patient and _device_busy():
         # fail-fast callers never wait on a busy device; only flag it
         # DEGRADED when the in-flight dispatch is past its own allowed
         # window (a healthy patient catch-up dispatch legitimately holds
@@ -1215,9 +1297,17 @@ def _verify_dense_routed(backend, pubs, sigs, msgs, lens, device,
         t0 = _time.perf_counter()
         wait = patient_wait_s(k) if patient else 0.0
         if valset_pubs is not None and scope is not None:
+            chunks = None
+            if patient and _device_busy():
+                # about to wait in ``queue`` behind the dispatch in
+                # flight: pack under that wait, on this thread, so the
+                # device-owner thread launches as soon as it is free
+                chunks = list(_packed_chunks(
+                    scope, pubs, rs, ss, msgs, lens,
+                    _resolve_devices(device), queued=True))
             out = _device_call(lambda: device_verify_ed25519_cached(
-                valset_pubs, scope, pubs, rs, ss, msgs, lens, device),
-                patient=wait)
+                valset_pubs, scope, pubs, rs, ss, msgs, lens, device,
+                chunks), patient=wait)
         else:
             out = _device_call(lambda: device_verify_ed25519(
                 pubs, rs, ss, msgs, lens, device), patient=wait)

@@ -318,6 +318,82 @@ def test_device_dispatch_hang_and_raise_degrade_to_host():
         B.set_device_wait(old_wait)
 
 
+@pytest.mark.parametrize("fault", ["hang", "raise"])
+def test_multi_chunk_patient_call_degrades_to_host_once(monkeypatch, fault):
+    """A patient call of three chunks whose dispatch hangs or raises:
+    the host's (right) answers, ONE abandonment however many chunks were
+    in flight, and the next call rides the device again.  The device's
+    programs are stand-ins that call every lane valid, so a lane named
+    bad can only have come from the host."""
+    import dataclasses
+
+    import numpy as np
+
+    from cometbft_tpu.crypto import batch as B
+    from cometbft_tpu.crypto import plan as P
+    from cometbft_tpu.crypto import rlc_finish
+    from cometbft_tpu.crypto.keys import Ed25519PrivKey
+
+    k, bad = 40, 20
+    keys = [Ed25519PrivKey.from_secret(b"chaos-chunk-%d" % i)
+            for i in range(k)]
+    msgs = np.frombuffer(b"".join(b"m%031d" % i for i in range(k)),
+                         np.uint8).reshape(k, 32)
+    sigs = np.frombuffer(b"".join(
+        key.sign(msgs[i].tobytes()) for i, key in enumerate(keys)),
+        np.uint8).reshape(k, 64).copy()
+    sigs[bad, 40] ^= 1
+    pubs = np.frombuffer(b"".join(key.pub_key().bytes() for key in keys),
+                         np.uint8).reshape(k, 32)
+    call = dict(pubs=pubs, sigs=sigs, msgs=msgs,
+                lens=np.full((k,), 32, np.int64), valset_pubs=pubs,
+                scope=np.arange(k, dtype=np.int64), patient=True)
+
+    launched = []
+    monkeypatch.setattr(B, "_compiled_prepare_tables", lambda: lambda p: (
+        np.zeros((1,), np.int32), np.ones((p.shape[0],), bool)))
+    monkeypatch.setattr(
+        B, "_compiled_rlc_gather",
+        lambda: lambda *a: launched.append(int(a[2][0]))
+        or rlc_finish.verdict(True))
+    monkeypatch.setattr(B, "_DEVICE_INFLIGHT", None)
+    saved, old_wait = P.active(), B._DEVICE_WAIT_S
+    P.set_plan(dataclasses.replace(saved, lane_buckets=(16,),
+                                   rlc_min_lanes=4), push_min_lanes=False)
+    B.set_device_wait(0.1)
+    gauge, abandoned = B._device_health()
+    lanes = B._metrics()[1]
+    before = abandoned.value(), lanes.value(route="device")
+    try:
+        F.configure(enabled=True, seed=3, faults=[
+            "device.dispatch.hang:at=1:delay=1.0" if fault == "hang"
+            else "device.dispatch.raise:at=1"])
+        ok, oks = B.verify_dense("jax", **call)
+        assert not ok and np.flatnonzero(~oks).tolist() == [bad]
+        assert abandoned.value() == before[0] + 1 and gauge.value() == 1
+        assert lanes.value(route="device") == before[1]
+        if fault == "hang":
+            assert launched == []
+            # the abandoned call's chunks run out on the device-owner
+            # thread afterwards and touch nobody's result
+            deadline = time.monotonic() + 60
+            while not B._DEVICE_INFLIGHT.done() \
+                    and time.monotonic() < deadline:
+                time.sleep(0.05)
+            assert launched == [0, 16, 32]
+            assert np.flatnonzero(~oks).tolist() == [bad]
+        del launched[:]
+        ok, oks = B.verify_dense("jax", **call)
+        assert ok and oks.all()         # the stand-ins' answer: the device
+        assert launched == [0, 16, 32]
+        assert abandoned.value() == before[0] + 1 and gauge.value() == 0
+        assert lanes.value(route="device") == before[1] + k
+    finally:
+        B.set_device_wait(old_wait)
+        P.set_plan(saved, push_min_lanes=False)
+        B._VALSET_TABLES.pop((id(pubs), ()), None)
+
+
 def test_logdb_enospc_fails_handle_closed(tmp_path):
     from cometbft_tpu.storage.db import LogDB
 

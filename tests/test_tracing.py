@@ -321,7 +321,8 @@ def test_verify_dense_spans_at_the_16_lane_bucket(monkeypatch):
     assert all(r["sub"] == "crypto.seam" for r in seam)
     attrs = {r["name"]: r["attrs"] for r in seam}
     assert attrs["tables"] == {"hit": True, "rows": 4}
-    assert attrs["pack"] == {"lanes": 3, "bucket": 16, "blocks": 2}
+    assert attrs["pack"] == {"lanes": 3, "bucket": 16, "blocks": 2,
+                             "ahead": False}
     assert attrs["launch"] == {"kind": "gather", "lanes": 3, "bucket": 16}
     assert attrs["readback"] == {"kind": "gather", "ok": True}
     assert len(recs) == 8                   # nothing else was recorded
