@@ -42,9 +42,11 @@ import time
 from . import plan as _plan
 
 _MAGIC = "cmt-aot"
-_FORMAT = 2          # 2: the RLC programs return their packed window
+_FORMAT = 3          # 2: the RLC programs return their packed window
 #   sums (crypto/rlc_finish.py), not a boolean: a format-1 bundle must
-#   never be loaded against the caller that folds them
+#   never be loaded against the caller that folds them.  3: the RLC
+#   programs select their window entries instead of gathering them; a
+#   saved format-2 bundle would go on serving the old executable
 
 _LOADED: dict[str, object] = {}      # bucket key -> loaded executable
 _INFO: dict = {"status": "absent", "buckets": {}}

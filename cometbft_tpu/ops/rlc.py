@@ -15,7 +15,7 @@ for the TPU's vector units:
 - The doublings are paid ONCE for the whole batch (64 windows x 4),
   not once per lane: the MSB-first ladder walks 4-bit windows of all
   scalars simultaneously.
-- Per window, each lane contributes one gathered table entry
+- Per window, each lane contributes one selected table entry
   ([digit](-Aᵢ) from the cached per-validator tables, [digit](-Rᵢ)
   from per-batch tables), and the lane contributions collapse through a
   **binary tree of cached-coordinate additions** (``group.add_cc``):
@@ -95,19 +95,26 @@ def host_rlc_coeffs(n: int, active_mask=None, rng_bytes=None) -> np.ndarray:
     return limbs.astype(np.int32)
 
 
-def _gather_all_windows(tab: Cached, digits) -> Cached:
+@jax.named_scope("rlc_sheet")
+def _window_sheet(tab: Cached, digits) -> tuple:
     """Per-lane table (16, 20, B) + per-window digits (B, NW) ->
     cached entries (20, B*NW), LANE-MAJOR: column b*NW + w holds lane
-    b's table row for window w.  Every window's gather happens at once,
-    and the lane-major order makes the whole (window x lane) sheet one
-    flat 2-D axis whose tree halving pairs lane b with lane b + B/2 for
-    every window simultaneously."""
+    b's table row for window w, so the whole (window x lane) sheet is
+    one flat 2-D axis whose tree halving pairs lane b with lane b + B/2
+    for every window simultaneously.
+
+    A lane's entry is one of its 16 rows, so it is SELECTED, not
+    gathered: Σ_j [digit == j]·tab[j] over all windows at once, one
+    elementwise-and-reduce that XLA fuses into a single pass over the
+    sheet (no gather, no transposed table, no 16-fold intermediate in
+    HBM).  On a v5e a gather of B·NW 80-byte slices costs over ten times
+    this pass (``PERF.md`` §5)."""
     nw = digits.shape[1]
+    hit = digits.reshape(-1) == jnp.arange(16, dtype=digits.dtype)[:, None]
 
     def one(c):
-        ct = jnp.transpose(c, (2, 0, 1))         # (B, 16, 20)
-        ent = jnp.take_along_axis(ct, digits[:, :, None], axis=1)
-        return jnp.transpose(ent, (2, 0, 1)).reshape(c.shape[1], -1)
+        rows = jnp.repeat(c, nw, axis=2)         # (16, 20, B*NW), fused
+        return jnp.sum(jnp.where(hit[:, None], rows, 0), axis=0)
 
     return Cached(*[one(c) for c in tab]), nw
 
@@ -160,10 +167,10 @@ def _rlc_sums(neg_a_tab, ok_a, rb, sb, blocks, active, z10):
     zh_dig = scalar.nibbles(zh)                  # (B, 64)
     z_dig = scalar.nibbles_k(z10, scalar.Z_NLIMBS, 32)   # (B, 32)
 
-    # all 64 (resp. 32) per-window lane sums at once: one gather + one
-    # shared tree — per-window sums (20, NW)
-    sum_a = _tree_reduce_lanes(*_gather_all_windows(neg_a_tab, zh_dig))
-    sum_r = _tree_reduce_lanes(*_gather_all_windows(neg_r_tab, z_dig))
+    # all 64 (resp. 32) per-window lane sums at once: one selection +
+    # one shared tree — per-window sums (20, NW)
+    sum_a = _tree_reduce_lanes(*_window_sheet(neg_a_tab, zh_dig))
+    sum_r = _tree_reduce_lanes(*_window_sheet(neg_r_tab, z_dig))
 
     # ok bits only bind on ACTIVE lanes (z != 0): padding lanes repeat
     # lane 0's bytes on some callers but carry arbitrary garbage on

@@ -8,14 +8,17 @@ import hashlib
 
 import numpy as np
 import jax
+import jax.numpy as jnp
 import pytest
 
-pytestmark = [pytest.mark.timeout(900), pytest.mark.slow]
+pytestmark = pytest.mark.timeout(900)
+slow = pytest.mark.slow     # whole RLC programs: minutes of CPU compile each
 
 from cometbft_tpu.crypto import _ed25519_py as ref
 from cometbft_tpu.crypto import _native_ed25519 as native
 from cometbft_tpu.crypto import rlc_finish
 from cometbft_tpu.ops import ed25519, rlc, scalar, fe
+from cometbft_tpu.ops.group import Cached
 from cometbft_tpu.testing import dense_signature_batch
 
 L = scalar.L_INT
@@ -31,6 +34,7 @@ def _ok(sums) -> bool:
     return rlc_finish.finish(np.asarray(sums))[0]
 
 
+@slow
 def test_mul_mod_l_and_sum_mod_l():
     rng = np.random.default_rng(11)
     xs = [int.from_bytes(rng.bytes(32), "little") for _ in range(24)]
@@ -48,12 +52,14 @@ def test_mul_mod_l_and_sum_mod_l():
     assert got < 2**256 and got % L == want % L
 
 
+@slow
 def test_rlc_accepts_valid_batch():
     args, _ = dense_signature_batch(24, msg_len=80, seed=42)
     ok = jax.jit(rlc.verify_batch_rlc)(*args, _z(24))
     assert _ok(ok)
 
 
+@slow
 def test_rlc_sums_fold_the_same_natively_and_in_python():
     """The kernel's real output at the 16-lane bucket: loose limbs (the
     chip does not freeze), one transfer, and both folds agree on it."""
@@ -71,6 +77,7 @@ def test_rlc_sums_fold_the_same_natively_and_in_python():
         assert native.rlc_fold(good) is True and native.rlc_fold(bad) is False
 
 
+@slow
 def test_rlc_sharded_returns_the_sums_of_the_whole_batch():
     """Two emulated devices, eight lanes each: the partial window sums
     combine into one replicated packed array that folds like the
@@ -88,6 +95,7 @@ def test_rlc_sharded_returns_the_sums_of_the_whole_batch():
     assert not _ok(fn(pub, rb, sb, blocks, active, _z(16)))
 
 
+@slow
 def test_rlc_rejects_each_tamper_surface():
     args, _ = dense_signature_batch(24, msg_len=80, seed=43)
     pub, rb, sb, blocks, active = [np.asarray(a).copy() for a in args]
@@ -107,6 +115,7 @@ def test_rlc_rejects_each_tamper_surface():
     assert _ok(fn(pub, rb, sb, blocks, active, z))
 
 
+@slow
 def test_rlc_padding_lanes_do_not_contribute():
     """z = 0 lanes (padding) are excluded from the sums: corrupt a
     padding lane's signature and the batch verdict must stay True."""
@@ -125,6 +134,7 @@ def test_rlc_padding_lanes_do_not_contribute():
     assert not _ok(ok2)
 
 
+@slow
 def test_rlc_invalid_padding_lane_cannot_veto():
     """Regression (ADVICE r5): the per-lane ok_a/ok_r/ok_s bits must be
     masked to ACTIVE lanes before the all-reduce.  A padding lane whose
@@ -149,6 +159,7 @@ def test_rlc_invalid_padding_lane_cannot_veto():
     assert not _ok(ok2)
 
 
+@slow
 def test_rlc_gather_variant_matches():
     """The cached-table route gives the same verdicts through a valset
     table + scope indices (the steady-state commit path)."""
@@ -169,6 +180,7 @@ def test_rlc_gather_variant_matches():
     assert not _ok(ok2)
 
 
+@slow
 def test_rlc_accepts_zip215_torsion_edge_cases():
     """Lanes whose defect is pure torsion (mixed-order A, small-order R,
     non-canonical identity A) are ZIP-215-valid and must pass the
@@ -230,3 +242,60 @@ def test_rlc_accepts_zip215_torsion_edge_cases():
         arr(pubs), arr([s[:32] for s in sigs]),
         arr([s[32:] for s in sigs]), blocks, active, _z(b))
     assert _ok(ok)
+
+
+def _gathered_sheet(tab, digits):
+    """The construction the selection replaced, kept as the oracle:
+    ``take_along_axis`` over tables transposed to (B, 16, 20), then back
+    to the lane-major (20, B*NW) sheet."""
+    def one(c):
+        ct = jnp.transpose(c, (2, 0, 1))
+        ent = jnp.take_along_axis(ct, digits[:, :, None], axis=1)
+        return jnp.transpose(ent, (2, 0, 1)).reshape(c.shape[1], -1)
+
+    return Cached(*[one(c) for c in tab])
+
+
+def _selected_sheet(tab, digits):
+    sheet, nw = rlc._window_sheet(tab, digits)
+    assert nw == digits.shape[1]
+    return sheet
+
+
+def _sheet_inputs(width, nw, seed):
+    """Random loose-limb tables (16, 20, width) and digits (width, nw)
+    in which every one of the 16 values occurs."""
+    rng = np.random.default_rng(seed)
+    tab = Cached(*[rng.integers(0, fe.MASK + 1, (16, fe.NLIMBS, width),
+                                dtype=np.int32) for _ in Cached._fields])
+    digits = rng.permutation(np.arange(width * nw) % 16)
+    return tab, digits.reshape(width, nw).astype(np.int32)
+
+
+@pytest.mark.parametrize("nw", [64, 32], ids=["A", "R"])
+@pytest.mark.parametrize("width", [1, 7, 256, 4096])
+def test_rlc_window_sheet_selects_what_the_gather_took(width, nw):
+    """Each (lane, window) entry of the selected sheet is the table row
+    its digit names, in the lane-major order the tree halves."""
+    tab, digits = _sheet_inputs(width, nw, seed=width + nw)
+    got = jax.jit(_selected_sheet)(tab, digits)
+    want = jax.jit(_gathered_sheet)(tab, digits)
+    for g, w in zip(got, want):
+        assert g.shape == (fe.NLIMBS, width * nw)
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("width,nw", [(1, 64), (7, 64), (256, 64),
+                                      (4096, 64), (1, 32), (7, 32),
+                                      (256, 32)])
+def test_rlc_window_sums_match_the_gathered_tree(width, nw):
+    """The per-window sums after the tree are the same cached
+    coordinates whichever way the sheet was built, identity padding of
+    a width that is not a power of two included."""
+    tab, digits = _sheet_inputs(width, nw, seed=3 * width + nw)
+    tree = jax.jit(rlc._tree_reduce_lanes, static_argnums=1)
+    got = tree(jax.jit(_selected_sheet)(tab, digits), nw)
+    want = tree(jax.jit(_gathered_sheet)(tab, digits), nw)
+    for g, w in zip(got, want):
+        assert g.shape == (fe.NLIMBS, nw)
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))

@@ -106,3 +106,8 @@ def test_sharded_rlc_compiles_for_four_v5e_chips(topo):
     assert "s32[20,386]" in hlo and rlc_finish.SHAPE == (20, 386)
     assert "rlc_ladder" not in hlo and "rlc_sums" in hlo
     assert not hasattr(rlc, "_rlc_ladder")
+    # each lane's window entry is selected, not gathered: the sheets'
+    # construction holds neither a gather nor a transposed table
+    sheet = [ln for ln in hlo.splitlines() if "/rlc_sheet/" in ln]
+    assert sheet
+    assert not [ln for ln in sheet if " gather(" in ln or " transpose(" in ln]
