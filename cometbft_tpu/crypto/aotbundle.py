@@ -42,11 +42,12 @@ import time
 from . import plan as _plan
 
 _MAGIC = "cmt-aot"
-_FORMAT = 3          # 2: the RLC programs return their packed window
+_FORMAT = 4          # 2: the RLC programs return their packed window
 #   sums (crypto/rlc_finish.py), not a boolean: a format-1 bundle must
 #   never be loaded against the caller that folds them.  3: the RLC
 #   programs select their window entries instead of gathering them; a
-#   saved format-2 bundle would go on serving the old executable
+#   saved format-2 bundle would go on serving the old executable.  4: the
+#   RLC lane tree runs in the tree form (ops/group.py add_tree)
 
 _LOADED: dict[str, object] = {}      # bucket key -> loaded executable
 _INFO: dict = {"status": "absent", "buckets": {}}

@@ -22,6 +22,8 @@ Representations (each component a limb array in the field layout):
 - extended: ``(X, Y, Z, T)``  with x = X/Z, y = Y/Z, T = XY/Z
 - cached:   ``(Y+X, Y-X, 2Z, 2dT)``   (general addition operand)
 - niels:    ``(Y+X, Y-X, 2dXY)``      (affine table entry, Z = 1)
+- tree:     ``(Y+X, Y-X, Z, kT)``, k = sqrt(2d)  (the RLC lane tree's
+  node form, ``add_tree``: kT·kT' is 2d·TT' with no constant)
 """
 
 from __future__ import annotations
@@ -53,6 +55,24 @@ class Niels(NamedTuple):
     t2d: jnp.ndarray
 
 
+class Tree(NamedTuple):
+    ypx: jnp.ndarray
+    ymx: jnp.ndarray
+    z: jnp.ndarray
+    tk: jnp.ndarray
+
+
+def sqrt_mod_p(a: int, p: int) -> int:
+    """The even square root of ``a`` mod ``p`` (p ≡ 5 mod 8), host-side,
+    for constants; raises if ``a`` is not a square."""
+    x = pow(a, (p + 3) // 8, p)
+    if x * x % p != a % p:
+        x = x * pow(2, (p - 1) // 4, p) % p
+    if x * x % p != a % p:
+        raise ValueError("not a square mod p")
+    return p - x if x & 1 else x
+
+
 def make_group(f) -> SimpleNamespace:
     """Instantiate the point ops over field module ``f``."""
     P, D = f.P_INT, f.D_INT
@@ -62,6 +82,9 @@ def make_group(f) -> SimpleNamespace:
     D2_C = f.const(2 * D % P)
     INV2_C = f.const(pow(2, P - 2, P))
     INV2D_C = f.const(pow(2 * D % P, P - 2, P))
+    K = sqrt_mod_p(2 * D % P, P)                  # k² ≡ 2d: 2d is a square
+    K_C = f.const(K)
+    K_INV2_C = f.const(2 * pow(K, P - 2, P))      # 2/k
 
     def identity(lane_shape=()) -> Ext:
         zero = f.bcast(ZERO_C, lane_shape)
@@ -129,6 +152,41 @@ def make_group(f) -> SimpleNamespace:
         return Cached(f.add(y3, x3), f.sub(y3, x3), f.add(z3, z3),
                       f.mul(t3, D2_C))
 
+    def tree_identity(lane_shape=()) -> Tree:
+        zero = f.bcast(ZERO_C, lane_shape)
+        one = f.bcast(ONE_C, lane_shape)
+        return Tree(one, one, one, zero)
+
+    def cached_to_tree(p: Cached) -> Tree:
+        """Cached -> tree form of the same point scaled by 2:
+        (2(Y+X), 2(Y-X), 2Z, 2kT), with 2kT = 2dT·(2/k)."""
+        return Tree(f.add(p.ypx, p.ypx), f.add(p.ymx, p.ymx), p.z2,
+                    f.mul(p.t2d, K_INV2_C))
+
+    def tree_to_cached(p: Tree) -> Cached:
+        """Tree -> cached: 2Z by an add, 2dT = k·(kT)."""
+        return Cached(p.ypx, p.ymx, f.add(p.z, p.z), f.mul(p.tk, K_C))
+
+    def add_tree(p: Tree, q: Tree) -> Tree:
+        """Tree x Tree -> Tree, the RLC lane tree's node.  The hwcd-2008
+        unified addition with C = kT1·kT2 = 2d·T1T2 and D = 2·Z1Z2, so the
+        operands need no constant factor: 9 field multiplications a node
+        (A, B, C, D, X3, Y3, Z3, T3 and k·T3) against ``add_cc``'s 11,
+        which spends 3 on the constants 1/(2d), 1/2 and 2d."""
+        a = f.mul(p.ymx, q.ymx)
+        b = f.mul(p.ypx, q.ypx)
+        c = f.mul(p.tk, q.tk)
+        d = f.mul(p.z, q.z)
+        d = f.add(d, d)
+        e = f.sub(b, a)
+        ff = f.sub(d, c)
+        g = f.add(d, c)
+        h = f.add(b, a)
+        x3 = f.mul(e, ff)
+        y3 = f.mul(g, h)
+        return Tree(f.add(y3, x3), f.sub(y3, x3), f.mul(ff, g),
+                    f.mul(f.mul(e, h), K_C))
+
     def cached_to_ext(p: Cached) -> Ext:
         """Cached -> extended (X = (ypx-ymx)/2, Y = (ypx+ymx)/2,
         Z = z2/2, T = t2d/(2d)); used once at the end of a tree."""
@@ -165,5 +223,7 @@ def make_group(f) -> SimpleNamespace:
     return SimpleNamespace(
         f=f, identity=identity, cache=cache, neg_ext=neg_ext, dbl=dbl,
         add_cached=add_cached, add_niels=add_niels, add_cc=add_cc,
+        tree_identity=tree_identity, cached_to_tree=cached_to_tree,
+        tree_to_cached=tree_to_cached, add_tree=add_tree, K=K,
         cached_to_ext=cached_to_ext, decompress_zip215=decompress_zip215,
         mul_by_cofactor=mul_by_cofactor, is_identity=is_identity)

@@ -18,10 +18,14 @@ for the TPU's vector units:
 - Per window, each lane contributes one selected table entry
   ([digit](-Aᵢ) from the cached per-validator tables, [digit](-Rᵢ)
   from per-batch tables), and the lane contributions collapse through a
-  **binary tree of cached-coordinate additions** (``group.add_cc``):
+  **binary tree of tree-form additions** (``group.add_tree``):
   log2(B) levels of halving-width vector adds — total group-op work
   ~B per window instead of ~6B for the per-lane ladder, and every
-  level is a dense vector op over the limb-major lane axis.
+  level is a dense vector op over the limb-major lane axis.  The tree
+  form ``(Y+X, Y-X, Z, √(2d)·T)`` needs no constant factor in a node;
+  the (16, 20, B) lane tables enter it once, before the window
+  selection (``_rlc_sums``), and the (20, NW) sums leave it once, at
+  the end of the tree, so the packed output stays in cached form.
 - The B term needs no tree: Σzᵢsᵢ mod L is a cheap mod-L sum, one
   scalar for the whole batch.
 - zᵢ is 128 bits, so the R tree only runs for the lower 32 windows.
@@ -56,7 +60,7 @@ import jax.numpy as jnp
 from ..crypto import rlc_finish
 from . import fe, scalar, sha512
 from .ed25519 import _build_neg_a_table, _g
-from .group import Cached
+from .group import Cached, Tree
 
 __all__ = ["verify_batch_rlc", "verify_batch_rlc_gather",
            "host_rlc_coeffs"]
@@ -95,13 +99,24 @@ def host_rlc_coeffs(n: int, active_mask=None, rng_bytes=None) -> np.ndarray:
     return limbs.astype(np.int32)
 
 
+def _table_to_tree(tab: Cached) -> Tree:
+    """Per-lane cached table (16, 20, B) -> the same table in the tree
+    form: 2 adds and 1 mul a row.  Row by row, each a (20, B) array as
+    ``fe_lm`` lays a field element out: under ``jax.vmap`` over the rows
+    the multiply's shifted accumulation lowered to unfused
+    dynamic-update-slices on a v5e (~40 ms a 4,096-lane dispatch)."""
+    rows = [_g.cached_to_tree(Cached(*[c[j] for c in tab]))
+            for j in range(tab.ypx.shape[0])]
+    return Tree(*[jnp.stack(cs) for cs in zip(*rows)])
+
+
 @jax.named_scope("rlc_sheet")
-def _window_sheet(tab: Cached, digits) -> tuple:
+def _window_sheet(tab: Tree, digits) -> tuple:
     """Per-lane table (16, 20, B) + per-window digits (B, NW) ->
-    cached entries (20, B*NW), LANE-MAJOR: column b*NW + w holds lane
-    b's table row for window w, so the whole (window x lane) sheet is
-    one flat 2-D axis whose tree halving pairs lane b with lane b + B/2
-    for every window simultaneously.
+    entries (20, B*NW) in the table's own point form, LANE-MAJOR:
+    column b*NW + w holds lane b's table row for window w, so the whole
+    (window x lane) sheet is one flat 2-D axis whose tree halving pairs
+    lane b with lane b + B/2 for every window simultaneously.
 
     A lane's entry is one of its 16 rows, so it is SELECTED, not
     gathered: Σ_j [digit == j]·tab[j] over all windows at once, one
@@ -116,35 +131,37 @@ def _window_sheet(tab: Cached, digits) -> tuple:
         rows = jnp.repeat(c, nw, axis=2)         # (16, 20, B*NW), fused
         return jnp.sum(jnp.where(hit[:, None], rows, 0), axis=0)
 
-    return Cached(*[one(c) for c in tab]), nw
+    return type(tab)(*[one(c) for c in tab]), nw
 
 
 @jax.named_scope("rlc_tree")
-def _tree_reduce_lanes(ents: Cached, nw: int) -> Cached:
-    """Binary tree of cached-coordinate additions over the lane-major
-    (20, W*NW) sheet -> per-window sums (20, NW).
+def _tree_reduce_lanes(ents: Tree, nw: int) -> Cached:
+    """Binary tree of tree-form additions over the lane-major
+    (20, W*NW) sheet -> per-window sums (20, NW), in cached form.
 
     All windows reduce simultaneously: the tree compiles ONCE for the
-    whole verdict (log2(W) add_cc levels) instead of once per window
-    body, and every level is a (20, (W/2)*NW)-wide vector op — the
-    narrow tail of a per-window tree gets NW-fold occupancy here.
+    whole verdict (log2(W) ``add_tree`` levels) instead of once per
+    window body, and every level is a (20, (W/2)*NW)-wide vector op —
+    the narrow tail of a per-window tree gets NW-fold occupancy here.
     Lanes pad to a power of two with identity entries (z = 0 padding
     lanes are already identity contributors, but arbitrary batch sizes
-    appear in tests)."""
+    appear in tests).  The sums leave the tree form here, one
+    multiplication on NW points, for ``_pack_sums`` and the sharded
+    combine."""
     w = ents.ypx.shape[1] // nw
     p2 = 1 << (w - 1).bit_length()
     if p2 != w:
-        idc = _g.cache(_g.identity(((p2 - w) * nw,)))
-        ents = Cached(*[jnp.concatenate([c, i_c], axis=1)
-                        for c, i_c in zip(ents, idc)])
+        idt = _g.tree_identity(((p2 - w) * nw,))
+        ents = Tree(*[jnp.concatenate([c, i_c], axis=1)
+                      for c, i_c in zip(ents, idt)])
         w = p2
     while w > 1:
         h = (w // 2) * nw
-        left = Cached(*[c[:, :h] for c in ents])
-        right = Cached(*[c[:, h:] for c in ents])
-        ents = _g.add_cc(left, right)
+        left = Tree(*[c[:, :h] for c in ents])
+        right = Tree(*[c[:, h:] for c in ents])
+        ents = _g.add_tree(left, right)
         w //= 2
-    return ents                                   # (20, NW)
+    return _g.tree_to_cached(ents)                # (20, NW)
 
 
 @jax.named_scope("rlc_sums")
@@ -167,10 +184,13 @@ def _rlc_sums(neg_a_tab, ok_a, rb, sb, blocks, active, z10):
     zh_dig = scalar.nibbles(zh)                  # (B, 64)
     z_dig = scalar.nibbles_k(z10, scalar.Z_NLIMBS, 32)   # (B, 32)
 
-    # all 64 (resp. 32) per-window lane sums at once: one selection +
-    # one shared tree — per-window sums (20, NW)
-    sum_a = _tree_reduce_lanes(*_window_sheet(neg_a_tab, zh_dig))
-    sum_r = _tree_reduce_lanes(*_window_sheet(neg_r_tab, z_dig))
+    # all 64 (resp. 32) per-window lane sums at once: the lane tables
+    # into the tree form, one selection + one shared tree — per-window
+    # sums (20, NW)
+    sum_a = _tree_reduce_lanes(*_window_sheet(_table_to_tree(neg_a_tab),
+                                              zh_dig))
+    sum_r = _tree_reduce_lanes(*_window_sheet(_table_to_tree(neg_r_tab),
+                                              z_dig))
 
     # ok bits only bind on ACTIVE lanes (z != 0): padding lanes repeat
     # lane 0's bytes on some callers but carry arbitrary garbage on

@@ -18,7 +18,7 @@ from cometbft_tpu.crypto import _ed25519_py as ref
 from cometbft_tpu.crypto import _native_ed25519 as native
 from cometbft_tpu.crypto import rlc_finish
 from cometbft_tpu.ops import ed25519, rlc, scalar, fe
-from cometbft_tpu.ops.group import Cached
+from cometbft_tpu.ops.group import Cached, Tree
 from cometbft_tpu.testing import dense_signature_batch
 
 L = scalar.L_INT
@@ -253,7 +253,7 @@ def _gathered_sheet(tab, digits):
         ent = jnp.take_along_axis(ct, digits[:, :, None], axis=1)
         return jnp.transpose(ent, (2, 0, 1)).reshape(c.shape[1], -1)
 
-    return Cached(*[one(c) for c in tab])
+    return type(tab)(*[one(c) for c in tab])
 
 
 def _selected_sheet(tab, digits):
@@ -263,11 +263,11 @@ def _selected_sheet(tab, digits):
 
 
 def _sheet_inputs(width, nw, seed):
-    """Random loose-limb tables (16, 20, width) and digits (width, nw)
-    in which every one of the 16 values occurs."""
+    """Random loose-limb tree-form tables (16, 20, width) and digits
+    (width, nw) in which every one of the 16 values occurs."""
     rng = np.random.default_rng(seed)
-    tab = Cached(*[rng.integers(0, fe.MASK + 1, (16, fe.NLIMBS, width),
-                                dtype=np.int32) for _ in Cached._fields])
+    tab = Tree(*[rng.integers(0, fe.MASK + 1, (16, fe.NLIMBS, width),
+                              dtype=np.int32) for _ in Tree._fields])
     digits = rng.permutation(np.arange(width * nw) % 16)
     return tab, digits.reshape(width, nw).astype(np.int32)
 
@@ -290,7 +290,7 @@ def test_rlc_window_sheet_selects_what_the_gather_took(width, nw):
                                       (256, 32)])
 def test_rlc_window_sums_match_the_gathered_tree(width, nw):
     """The per-window sums after the tree are the same cached
-    coordinates whichever way the sheet was built, identity padding of
+    coordinates whichever way the tree-form sheet was built, identity padding of
     a width that is not a power of two included."""
     tab, digits = _sheet_inputs(width, nw, seed=3 * width + nw)
     tree = jax.jit(rlc._tree_reduce_lanes, static_argnums=1)
@@ -299,3 +299,220 @@ def test_rlc_window_sums_match_the_gathered_tree(width, nw):
     for g, w in zip(got, want):
         assert g.shape == (fe.NLIMBS, nw)
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+# --- the tree form of the lane tree (ops/group.py add_tree) -------------
+
+P = fe.P_INT
+_gl = ed25519._g
+
+
+def _val(limbs) -> int:
+    return fe.int_from_limbs(limbs) % P
+
+
+def _cached_affine(ypx, ymx, z2) -> tuple[int, int]:
+    """Cached coordinates (ints) -> affine (x, y): x = (ypx - ymx)/z2,
+    y = (ypx + ymx)/z2 (the halves of X, Y and Z cancel)."""
+    zi = pow(z2, P - 2, P)
+    return (ypx - ymx) * zi % P, (ypx + ymx) * zi % P
+
+
+def _same_point(c1, c2) -> bool:
+    """Two cached points (ints) projectively equal: X·Z' - X'·Z = 0 and
+    the same for Y, on frozen values; and each one's 2dT consistent
+    with its X, Y, Z (2dT·2Z = 2d·(2X)(2Y)/2)."""
+    (p1, m1, z1, t1), (p2, m2, z2, t2) = c1, c2
+    ok = ((p1 - m1) * z2 - (p2 - m2) * z1) % P == 0
+    ok &= ((p1 + m1) * z2 - (p2 + m2) * z1) % P == 0
+    for p_, m_, z_, t_ in (c1, c2):
+        ok &= z_ % P != 0
+        ok &= (t_ * z_ - fe.D_INT * (p_ - m_) * (p_ + m_)) % P == 0
+    return bool(ok)
+
+
+def _ext_limbs(pts, rng) -> "Ext":
+    """Affine-equivalent extended points (ints) -> limb-major (20, n)
+    Ext with a random projective scale per lane."""
+    from cometbft_tpu.ops.group import Ext
+
+    cols = [[], [], [], []]
+    for x, y, z, t in pts:
+        zi = pow(z, P - 2, P)
+        ax, ay = x * zi % P, y * zi % P
+        s_ = int(rng.integers(1, 2**62))
+        for k, v in enumerate((ax * s_, ay * s_, s_, ax * ay % P * s_)):
+            cols[k].append(fe.limbs_from_int(v % P))
+    return Ext(*[np.stack(c, axis=1).astype(np.int32) for c in cols])
+
+
+def _torsion8():
+    """A point of order 8 (decoded from a fixed encoding's multiple)."""
+    for i in range(1000):
+        pt = ref.pt_decompress_zip215(hashlib.sha256(bytes([i])).digest())
+        if pt is None:
+            continue
+        t = ref.pt_mul(ref.L, pt)
+        if not ref.pt_equal(ref.pt_mul(4, t), ref.IDENTITY):
+            return t
+    raise AssertionError("no order-8 point found")
+
+
+def _node_pairs():
+    """(case, P, Q) operand pairs of one group-level batch."""
+    rng = np.random.default_rng(41)
+    rand = [ref.pt_mul(int(rng.integers(1, 2**62)), ref.BASE)
+            for _ in range(6)]
+    t8 = _torsion8()
+    two = (0, P - 1, 1, 0)                      # the point of order 2
+    return [("random", rand[0], rand[1]), ("random", rand[2], rand[3]),
+            ("double", rand[4], rand[4]), ("double", t8, t8),
+            ("inverse", rand[5], ref.pt_neg(rand[5])),
+            ("inverse", t8, ref.pt_neg(t8)),
+            ("identity", ref.IDENTITY, rand[0]),
+            ("identity", ref.IDENTITY, ref.IDENTITY),
+            ("small_order", t8, rand[1]), ("small_order", two, rand[2]),
+            ("small_order", two, t8)]
+
+
+@pytest.fixture(scope="module")
+def node_results():
+    pairs = _node_pairs()
+    rng = np.random.default_rng(42)
+    p_ext = _ext_limbs([p for _, p, _ in pairs], rng)
+    q_ext = _ext_limbs([q for _, _, q in pairs], rng)
+
+    def both(p, q):
+        cp, cq = _gl.cache(p), _gl.cache(q)
+        old = _gl.add_cc(cp, cq)
+        new = _gl.tree_to_cached(_gl.add_tree(_gl.cached_to_tree(cp),
+                                              _gl.cached_to_tree(cq)))
+        return old, new
+
+    old, new = jax.jit(both)(p_ext, q_ext)
+    return pairs, [np.asarray(c) for c in old], [np.asarray(c) for c in new]
+
+
+def test_tree_form_constant_is_a_square_root_of_2d():
+    k = _gl.K
+    assert k * k % P == 2 * fe.D_INT % P
+    assert k % 2 == 0 and 0 < k < P
+
+
+@pytest.mark.parametrize("case", ["random", "double", "inverse",
+                                  "identity", "small_order"])
+def test_add_tree_is_add_cc_as_a_group_element(node_results, case):
+    """``add_tree`` between the two conversions gives the point
+    ``add_cc`` gives and the affine sum of the pure-Python oracle,
+    projectively (the limbs differ: the forms scale differently)."""
+    pairs, old, new = node_results
+    lanes = [i for i, (c, _, _) in enumerate(pairs) if c == case]
+    assert lanes
+    for i in lanes:
+        _, p, q = pairs[i]
+        o = tuple(_val(c[:, i]) for c in old)
+        n = tuple(_val(c[:, i]) for c in new)
+        assert _same_point(o, n), (case, i)
+        want = ref.pt_add(p, q)
+        zi = pow(want[2], P - 2, P)
+        assert _cached_affine(*n[:3]) == (want[0] * zi % P,
+                                          want[1] * zi % P), (case, i)
+        assert all(0 <= c[:, i].min() and c[:, i].max() <= fe.LIMB_MAX
+                   for c in new)
+
+
+def _cc_tree(ents: Cached, nw: int) -> Cached:
+    """The ``add_cc`` lane tree the tree form replaced, kept as the
+    oracle: the cached sheet halved with identity padding."""
+    w = ents.ypx.shape[1] // nw
+    p2 = 1 << (w - 1).bit_length()
+    if p2 != w:
+        idc = _gl.cache(_gl.identity(((p2 - w) * nw,)))
+        ents = Cached(*[jnp.concatenate([c, i_c], axis=1)
+                        for c, i_c in zip(ents, idc)])
+        w = p2
+    while w > 1:
+        h = (w // 2) * nw
+        ents = _gl.add_cc(Cached(*[c[:, :h] for c in ents]),
+                          Cached(*[c[:, h:] for c in ents]))
+        w //= 2
+    return ents
+
+
+_TREE_LANES = 1024
+
+
+@pytest.fixture(scope="module")
+def real_tables():
+    """Cached [j](-A) tables (16, 20, 1024) of real keys
+    (``prepare_pubkey_tables``: decompression + ``_build_neg_a_table``)
+    and each lane's scalar mod L: 8 keys [a]B, the identity, a point of
+    order 8 and a mixed-order key [a]B + T8 (their torsion dies under
+    the fold's cofactor)."""
+    rng = np.random.default_rng(44)
+    t8 = _torsion8()
+    pool = []
+    for _ in range(8):
+        a = int(rng.integers(1, 2**62)) * int(rng.integers(1, 2**62))
+        pool.append((ref.pt_compress(ref.pt_mul(a, ref.BASE)), a))
+    a = int(rng.integers(1, 2**62))
+    pool += [(ref.pt_compress(ref.IDENTITY), 0), (ref.pt_compress(t8), 0),
+             (ref.pt_compress(ref.pt_add(ref.pt_mul(a, ref.BASE), t8)), a)]
+    pick = rng.integers(0, len(pool), _TREE_LANES)
+    pick[:len(pool)] = np.arange(len(pool))
+    pub = np.stack([np.frombuffer(pool[k][0], np.uint8) for k in pick]
+                   ).astype(np.int32)
+    tab, ok = jax.jit(ed25519.prepare_pubkey_tables)(pub)
+    assert np.asarray(ok).all()
+    return Cached(*[np.asarray(c) for c in tab]), [pool[k][1] for k in pick]
+
+
+def _digits(rng, width, nw):
+    """Random digits, every fourth lane all zero (a z = 0 lane)."""
+    d = rng.integers(0, 16, (width, nw)).astype(np.int32)
+    d[::4] = 0
+    return d
+
+
+@pytest.mark.parametrize("width", [1, 7, 256, 1024])
+def test_rlc_tree_form_sums_match_the_add_cc_tree(real_tables, width):
+    """On real tables the tree-form lane tree gives, window by window,
+    the point the ``add_cc`` tree gives, identity padding and z = 0
+    lanes included; packed with the Σzs that balances them, both fold
+    to True natively and in Python, and to False with Σzs off by one.
+    The A sheet's 64 windows only (the R sums are the identity): the
+    tree does not depend on the window count, and each tree costs a
+    compile."""
+    full_tab, scal = real_tables
+    tab = Cached(*[c[:, :, :width] for c in full_tab])
+    dig = _digits(np.random.default_rng(width), width, 64)
+
+    def new(tab, dig):
+        tt = rlc._table_to_tree(tab)
+        return rlc._tree_reduce_lanes(*rlc._window_sheet(tt, dig))
+
+    def old(tab, dig):
+        return _cc_tree(*rlc._window_sheet(tab, dig))
+
+    got = [np.asarray(c) for c in jax.jit(new)(tab, dig)]
+    want = [np.asarray(c) for c in jax.jit(old)(tab, dig)]
+    for c in got:
+        assert c.shape == (fe.NLIMBS, 64)
+        assert 0 <= c.min() and c.max() <= fe.LIMB_MAX
+    for col in range(64):
+        assert _same_point(tuple(_val(c[:, col]) for c in got),
+                           tuple(_val(c[:, col]) for c in want)), col
+
+    ident = _gl.cache(_gl.identity((32,)))
+    zs = sum(a * sum(int(v) << (4 * k) for k, v in enumerate(row))
+             for row, a in zip(dig, scal))
+    for sums in (got, want):
+        for off, expect in ((0, True), (1, False)):
+            packed = np.asarray(rlc._pack_sums(
+                Cached(*sums), ident,
+                jnp.asarray(fe.limbs_from_int((zs + off) % L)),
+                jnp.asarray(True)))
+            assert bool(rlc_finish.fold_python(packed)) == expect
+            if native.available():
+                assert native.rlc_fold(packed) is expect
+            assert bool(rlc_finish.finish(packed)[0]) == expect
